@@ -47,7 +47,7 @@ from repro.core.mttdl import MTTDLParams, code_mttdl_years
 from repro.core.placement import Placement, default_placement
 from repro.io.backend import Backend, resolve_backend
 from repro.io.engine import CodingEngine, OpHandle
-from repro.kernels import ops
+from repro.kernels import autotune, ops
 
 from repro.topo import Topology
 
@@ -128,21 +128,26 @@ class StripeCodec:
     call: peak memory for encode is ~max_batch_stripes * n * block_size
     bytes (host staging + codeword array), so an unbounded batch over a
     checkpoint-scale buffer would OOM where the launch count barely
-    changes. 64 stripes of 1 MiB blocks ≈ 13 GiB codeword ceiling for the
-    widest paper code; launches stay at ceil(S/64) instead of S."""
+    changes. The default comes from `autotune.plan_stream_windows`: 64
+    for small blocks, 3 for the widest paper code at 1 MiB blocks, where
+    64 stripes would be a ~13 GiB codeword window and two of them would
+    not fit a v5e's 16 GiB of HBM."""
 
     def __init__(self, code: Code, store: BlockStore, *,
                  block_size: int = 1 << 20,
                  placement: Placement | None = None,
                  backend: Backend | str | None = None,
                  use_kernels: bool | None = None,    # deprecated shim
-                 max_batch_stripes: int = 64,
+                 max_batch_stripes: int | None = None,
                  gateway_aggregation: bool = False):
         self.code = code
         self.store = store
         self.block_size = block_size
         self.placement = placement or default_placement(code)
         self.backend = resolve_backend(backend, use_kernels=use_kernels)
+        if max_batch_stripes is None:
+            max_batch_stripes = autotune.plan_stream_windows(
+                code.k, code.n, block_size)
         self.use_kernels = self.backend.uses_kernels
         self.engine = CodingEngine(code, store, self.backend,
                                    max_batch_stripes=max_batch_stripes,

@@ -18,9 +18,12 @@ one grid step of the bit-plane coding matmul holds, per (m, k, Bt),
     acc    fp32   8m * Bt * 4   MXU accumulator
     bytes  uint8  (k + m) * Bt  in/out byte tiles
 
-and the XOR fold holds (s + 1) * Bt_lanes int32 lanes. The budget
-defaults to 8 MiB (the header's "< 8 MiB of the v5e's ~64 MiB/core" —
-leaving room for Pallas double-buffering of the streamed operands).
+and the XOR fold holds (s + 1) * Bt_words 4-byte words. The budget
+defaults to 8 MiB, which leaves room for Pallas double-buffering of the
+streamed operands. The v5e compiler accepts the tiles this budget plans
+at 1 MiB blocks for 180-of-210 and 30-of-42 (tests/test_tpu_compile.py);
+that is the compiler's verdict on these shapes, not a measured VMEM
+ceiling.
 
 Tile selection: lane tiles must be multiples of 128 (TPU lane count);
 among the candidates that fit the budget the planner first minimises
@@ -59,7 +62,7 @@ import time
 
 LANE = 128                       # TPU lane count: tiles are multiples
 MAX_MATMUL_BLOCK_B = 4096        # bytes — grid-step floor for huge B
-MAX_XOR_BLOCK_LANES = 8192       # int32 lanes (32 KiB)
+MAX_XOR_BLOCK_LANES = 8192       # 4-byte words (32 KiB)
 DEFAULT_VMEM_BUDGET = 8 << 20    # bytes, see module docstring
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 _TIMINGS_VERSION = 1
@@ -70,7 +73,7 @@ class TilePlan:
     """One lane-dimension tiling decision.
 
     `block_b` and `padded` are in the kernel's lane units: bytes for
-    the GF matmul, int32 lanes for the XOR fold. `pad` is the wasted
+    the GF matmul, 4-byte words for the XOR fold. `pad` is the wasted
     lane-units per row (`padded - size`); `grid_steps` the per-stripe
     grid extent; `vmem_bytes` the modeled residency of one grid step.
     `source` records whether the choice came from the analytic model
@@ -93,8 +96,8 @@ def matmul_vmem_bytes(k: int, m: int, block_b: int) -> int:
 
 
 def xor_vmem_bytes(s: int, block_lanes: int) -> int:
-    """Modeled VMEM bytes of one xor_reduce grid step: the (s, Bt)
-    int32 source tile plus the (Bt,) fold output."""
+    """Modeled VMEM bytes of one xor_reduce grid step: the (s, 4 * Bt)
+    byte source tile plus the (4 * Bt,) fold output."""
     return (s + 1) * block_lanes * 4
 
 
@@ -213,8 +216,8 @@ def plan_matmul_tiles(k: int, m: int, B: int, *,
 @functools.lru_cache(maxsize=512)
 def plan_xor_tiles(s: int, nbytes: int, *,
                    vmem_budget: int = DEFAULT_VMEM_BUDGET) -> TilePlan:
-    """Lane tile (int32 lanes) for an s-source XOR fold of B-byte rows.
-    Bytes pad up to 4 * block_b (the int32 bitcast) in ops.py."""
+    """Lane tile, in 4-byte words, for an s-source XOR fold of B-byte
+    rows. ops.py runs the kernel on the bytes with a 4 * block_b tile."""
     lanes = -(-max(nbytes, 1) // 4)
 
     def fits(tile: int) -> bool:
@@ -235,13 +238,20 @@ def plan_xor_tiles(s: int, nbytes: int, *,
 def plan_stream_windows(k: int, n: int, block_size: int, *,
                         host_budget_bytes: int = 1 << 31,
                         cap: int = 64) -> int:
-    """Stripe-batch window for the streaming checkpoint write path.
+    """Stripe-batch window: the default `max_batch_stripes` of
+    `StripeCodec`, and so the window of the streaming checkpoint write.
 
     The double-buffered pipeline holds at most TWO windows of (n,
     block_size) codewords plus one (k, block_size) input view per
-    stripe; pick the largest window (<= cap, the engine's
-    max_batch_stripes default) whose staging fits `host_budget_bytes`
-    of host memory. Always >= 1."""
+    stripe; pick the largest window (<= cap) whose staging fits
+    `host_budget_bytes`. Always >= 1.
+
+    The same bound keeps the device side in one chip's HBM: a window's
+    encode holds its (k, B) input, (m, B) parities and (n, B) codeword
+    per stripe, (k + m + n) * B < (2n + k) * B, so two live windows stay
+    under 2 * host_budget_bytes (4 GiB by default; a v5e has 16 GiB).
+    The 180-of-210 code at 1 MiB blocks gets 3 stripes; a fixed 64 would
+    put two ~13 GiB codeword windows on the device."""
     per_stripe = (2 * n + k) * block_size
     return max(1, min(cap, host_budget_bytes // max(per_stripe, 1)))
 

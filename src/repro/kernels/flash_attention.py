@@ -35,6 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -138,13 +139,9 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
         _flash_fwd_kernel, scale=scale, causal=causal, window=window,
         block_q=bq, block_k=bk, nk=nk)
 
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        scratch = [pltpu.VMEM((bq, 128), jnp.float32),
-                   pltpu.VMEM((bq, 128), jnp.float32),
-                   pltpu.VMEM((bq, dv), jnp.float32)]
-    except ImportError:  # pragma: no cover
-        scratch = [pl.MemorySpace.ANY] * 3
+    scratch = [pltpu.VMEM((bq, 128), jnp.float32),
+               pltpu.VMEM((bq, 128), jnp.float32),
+               pltpu.VMEM((bq, dv), jnp.float32)]
 
     out, lse = pl.pallas_call(
         kernel,

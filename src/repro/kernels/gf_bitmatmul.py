@@ -20,12 +20,13 @@ with exact fp32 accumulation (8k <= 2^24 summands). The kernel:
 Grid: (B // Bt,) — parity rows are small (m <= 30 for the paper's widest
 code => 8m <= 240 MXU rows), so m is not tiled; the byte stream is.
 
-Tile maths for VMEM (v5e ~64 MiB/core, we budget < 8 MiB):
+Tile maths for VMEM (the planner's 8 MiB budget, kernels/autotune.py):
   A_bits fp32: 8m*8k*4  = 240*1440*4   = 1.4 MiB  (n=210 code)
   x_bits fp32: 8k*Bt*4  = 1440*512*4   = 2.8 MiB
   out fp32:    8m*Bt*4  = 240*512*4    = 0.5 MiB
-MXU dims: 8k = 1440 and 8m = 240 are multiples of 8/128-friendly; Bt = 512
-keeps the lane dimension a multiple of 128.
+The v5e compiler accepts both planned shapes at 1 MiB blocks: k=180/m=30
+at Bt=512 and k=30/m=12 at Bt=4096 (tests/test_tpu_compile.py). Bt is a
+multiple of 128, the TPU lane count.
 """
 from __future__ import annotations
 
@@ -49,8 +50,10 @@ def _code_tile(a_bits, data, *, m: int, k: int):
         jax.lax.shift_right_logical(d32[:, None, :], shifts), 1)
     x_bits = bits.reshape(8 * k, bt).astype(jnp.float32)   # (8k, Bt)
 
+    # Mosaic has no uint8 -> f32 cast; widen to int32 first.
+    a_f32 = a_bits.astype(jnp.int32).astype(jnp.float32)
     acc = jax.lax.dot_general(
-        a_bits.astype(jnp.float32), x_bits,
+        a_f32, x_bits,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                # (8m, Bt)
     acc_i = acc.astype(jnp.int32) & 1                      # mod 2

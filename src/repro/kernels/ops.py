@@ -14,8 +14,9 @@ Stripe-batched variants (leading S axis, ONE kernel launch per call):
   recover_many(plan, blocks)    -> {src: (S, B)} -> (S, B)
   apply_decode_many(plan, blocks) -> {src: (S, B)} -> {erased: (S, B)}
 
-`interpret` defaults to True on CPU (this container) and False when a real
-TPU is attached — the Pallas kernel body is identical.
+`interpret` defaults to True when JAX's backend is the CPU and False on a
+TPU (`default_interpret`); any other backend raises. The Pallas kernel
+body is identical in both modes.
 
 KERNEL_LAUNCHES counts pallas_call launches per kernel (host-side, outside
 jit) so tests and benchmarks can assert batching actually batches. All
@@ -125,12 +126,20 @@ def launches_since(snapshot: dict[str, int]) -> int:
     return total - sum(snapshot.values())
 
 
-def _on_tpu() -> bool:
-    return any(d.platform == "tpu" for d in jax.devices())
-
-
+@functools.cache
 def default_interpret() -> bool:
-    return not _on_tpu()
+    """True (Pallas interpret mode) when JAX's backend is the CPU, False on
+    a TPU. Any other backend raises: the kernels are written for the TPU,
+    and interpret mode anywhere but the CPU would hide that the device is
+    not running them. Decided once per process."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"the coding kernels run on a TPU, or in interpret mode on the "
+        f"CPU; JAX's backend is {platform!r}")
 
 
 def _pad_to(x: np.ndarray | jax.Array, mult: int, axis: int):
@@ -146,7 +155,10 @@ def _pad_to(x: np.ndarray | jax.Array, mult: int, axis: int):
 @functools.lru_cache(maxsize=64)
 def _a_bits_for(code_key: str, A_bytes: bytes, shape: tuple) -> jax.Array:
     A = np.frombuffer(A_bytes, dtype=np.uint8).reshape(shape)
-    return jnp.asarray(expand_coding_matrix_to_bits(A))
+    # Concrete even when the first call is traced under jit: a cached
+    # tracer would leak into every later call.
+    with jax.ensure_compile_time_eval():
+        return jnp.asarray(expand_coding_matrix_to_bits(A))
 
 
 def _bits(A: np.ndarray, tag: str) -> jax.Array:
@@ -220,20 +232,20 @@ def encode_many(code: Code, data: jax.Array, *,
 
 
 def xor_fold(blocks: jax.Array, *, interpret: bool | None = None) -> jax.Array:
-    """(s, B) uint8 -> (B,) uint8 XOR-fold, on int32 lanes."""
+    """(s, B) uint8 -> (B,) uint8 XOR-fold.
+
+    The kernel folds the bytes as they are. The planner sizes the tile in
+    4-byte words, so the byte tile is 4 * plan.block_b. (Viewing the bytes
+    as int32 outside the kernel costs the TPU compiler ~80 s per shape to
+    unpack the result, and a 32x lane-padded copy to pack the input.)"""
     if interpret is None:
         interpret = default_interpret()
     blocks = jnp.asarray(blocks, dtype=jnp.uint8)
     s, B = blocks.shape
-    plan = autotune.plan_xor_tiles(s, B)        # lane tile, in int32 lanes
-    padded, _ = _pad_to(blocks, 4 * plan.block_b, axis=1)
-    lanes = jax.lax.bitcast_convert_type(
-        padded.reshape(s, -1, 4), jnp.int32).reshape(s, -1)
+    tile = 4 * autotune.plan_xor_tiles(s, B).block_b
+    padded, _ = _pad_to(blocks, tile, axis=1)
     _count_launch("xor_reduce")
-    out32 = xor_reduce(lanes, block_b=plan.block_b, interpret=interpret)
-    out8 = jax.lax.bitcast_convert_type(
-        out32.reshape(-1, 1), jnp.uint8).reshape(-1)
-    return out8[:B]
+    return xor_reduce(padded, block_b=tile, interpret=interpret)[:B]
 
 
 def xor_fold_many(blocks: jax.Array, *,
@@ -243,16 +255,11 @@ def xor_fold_many(blocks: jax.Array, *,
         interpret = default_interpret()
     blocks = jnp.asarray(blocks, dtype=jnp.uint8)
     S, s, B = blocks.shape
-    plan = autotune.plan_xor_tiles(s, B)
-    padded, _ = _pad_to(blocks, 4 * plan.block_b, axis=2)
-    lanes = jax.lax.bitcast_convert_type(
-        padded.reshape(S, s, -1, 4), jnp.int32).reshape(S, s, -1)
+    tile = 4 * autotune.plan_xor_tiles(s, B).block_b      # see xor_fold
+    padded, _ = _pad_to(blocks, tile, axis=2)
     _count_launch("xor_reduce")
-    out32 = xor_reduce_batched(lanes, block_b=plan.block_b,
-                               interpret=interpret)
-    out8 = jax.lax.bitcast_convert_type(
-        out32.reshape(S, -1, 1), jnp.uint8).reshape(S, -1)
-    return out8[:, :B]
+    return xor_reduce_batched(padded, block_b=tile,
+                              interpret=interpret)[:, :B]
 
 
 def recover_single(plan: RecoveryPlan, blocks: dict[int, jax.Array], *,

@@ -2,12 +2,12 @@
 decode path (XOR locality, paper §2.3.3/§4.1 Property 2).
 
 The TPU analogue of the paper's Fig 3 "XOR beats MUL+XOR" result: this
-kernel is a pure VPU bitwise reduction on int32 lanes — no MXU pass, no
+kernel is a pure VPU bitwise reduction — no MXU pass, no
 table gathers, ~s*B byte reads and B writes. Compare kernels/gf_bitmatmul
 (the MUL+XOR path) which needs an (8m x 8k x Bt) MXU contraction.
 
-Blocks are viewed as int32 lanes (4 bytes per lane) by ops.py; the kernel
-itself is dtype-agnostic over integer types.
+ops.py hands it the uint8 blocks as they are; the kernel itself is
+dtype-agnostic over integer types.
 """
 from __future__ import annotations
 
@@ -16,7 +16,7 @@ import functools
 import jax
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_B = 2048  # int32 lanes per tile (= 8 KiB of payload)
+DEFAULT_BLOCK_B = 2048  # elements per tile
 
 
 def _kernel(blocks_ref, out_ref, *, s: int):
@@ -30,7 +30,7 @@ def _kernel_batched(blocks_ref, out_ref, *, s: int):
     acc = blocks_ref[0, 0, :]
     for j in range(1, s):
         acc = acc ^ blocks_ref[0, j, :]
-    out_ref[0] = acc
+    out_ref[0, 0, :] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -57,15 +57,20 @@ def xor_reduce_batched(blocks: jax.Array, block_b: int = DEFAULT_BLOCK_B,
 
     The stripe-batch analogue of `xor_reduce`: grid (S, B // block_b), so
     recovering the same failed block across S stripes is a single kernel
-    launch instead of S."""
+    launch instead of S.
+
+    The output is laid out (S, 1, B): a TPU block's last two dims must be
+    multiples of (8, 128) or equal the array's, and a one-stripe (1, Bt)
+    block of an (S, B) array is neither."""
     S, s, B = blocks.shape
     assert B % block_b == 0, (B, block_b)
     grid = (S, B // block_b)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel_batched, s=s),
         grid=grid,
         in_specs=[pl.BlockSpec((1, s, block_b), lambda si, b: (si, 0, b))],
-        out_specs=pl.BlockSpec((1, block_b), lambda si, b: (si, b)),
-        out_shape=jax.ShapeDtypeStruct((S, B), blocks.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_b), lambda si, b: (si, 0, b)),
+        out_shape=jax.ShapeDtypeStruct((S, 1, B), blocks.dtype),
         interpret=interpret,
     )(blocks)
+    return out.reshape(S, B)

@@ -6,13 +6,14 @@ TPU artifacts. Hypothesis-based kernel properties live in
 tests/test_kernels_property.py so this module runs on minimal
 environments without hypothesis.
 """
+import jax
 import numpy as np
 import pytest
 
 from repro.core import make_unilrc, paper_schemes
 from repro.core.codec import decode_plan, single_recovery_plan
 from repro.core.gf import expand_coding_matrix_to_bits, gf_matmul
-from repro.kernels import apply_decode, encode, recover_single
+from repro.kernels import apply_decode, encode, ops, recover_single
 from repro.kernels.gf_bitmatmul import gf_bitmatmul
 from repro.kernels.ref import gf_bitmatmul_ref, gf_matmul_ref
 from repro.kernels.xor_reduce import xor_reduce
@@ -91,6 +92,19 @@ def test_encode_wide_210():
     assert np.array_equal(got, code.encode(data))
 
 
+def test_encode_traced_first_then_eager():
+    """The coding-matrix cache filled by a call traced under jit must
+    serve a later eager call (no leaked tracer)."""
+    code = make_unilrc(1, 3)
+    rng = np.random.default_rng(10)
+    data = rng.integers(0, 256, (2, code.k, 256), dtype=np.uint8)
+    want = np.stack([code.encode(d) for d in data])
+    ops._a_bits_for.cache_clear()
+    traced = jax.jit(lambda x: ops.encode_many(code, x))(data)
+    assert np.array_equal(np.asarray(traced), want)
+    assert np.array_equal(np.asarray(ops.encode_many(code, data)), want)
+
+
 def test_recover_single_xor_path():
     code = make_unilrc(1, 6)
     rng = np.random.default_rng(7)
@@ -122,6 +136,22 @@ def test_ref_table_path_matches_host():
     M = rng.integers(0, 256, (6, 13), dtype=np.uint8)
     x = rng.integers(0, 256, (13, 640), dtype=np.uint8)
     assert np.array_equal(np.asarray(gf_matmul_ref(M, x)), gf_matmul(M, x))
+
+
+def test_default_interpret_only_on_cpu(monkeypatch):
+    """Interpret mode is for the CPU; a backend that is neither the CPU
+    nor a TPU is refused instead of silently interpreting the kernels."""
+    assert ops.default_interpret() is True          # the CPU test backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    ops.default_interpret.cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops.default_interpret()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        ops.default_interpret.cache_clear()
+        assert ops.default_interpret() is False
+    finally:
+        ops.default_interpret.cache_clear()
 
 
 # ---------------------------------------------------------------------------
