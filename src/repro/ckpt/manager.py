@@ -22,6 +22,7 @@ import dataclasses
 import time
 from typing import Any
 
+from repro import obs
 from repro.core.codes import Code
 
 from .serialize import Manifest, deserialize_tree, serialize_tree
@@ -84,8 +85,9 @@ class CheckpointManager:
 
     def save(self, state: Any, step: int) -> int:
         """Returns the number of stripes written."""
-        buf, manifest, treedef = serialize_tree(state)
-        metas = self.write_checkpoint(buf)
+        with obs.span("ckpt.save", step=step):
+            buf, manifest, treedef = serialize_tree(state)
+            metas = self.write_checkpoint(buf)
         self._saved[step] = _Saved(metas, manifest, treedef)
         return len(metas)
 
@@ -121,15 +123,17 @@ class CheckpointManager:
         degraded = 0
         total = 0
         parts = []
-        for meta in sv.metas:
-            for b in range(self.code.k):
-                total += 1
-                if not self.store.available(meta.stripe_id, b):
-                    degraded += 1
-            parts.append(self.codec.normal_read(
-                meta, reader_cluster=reader_cluster))
-        buf = b"".join(parts)[:sv.manifest.total_bytes]
-        state = deserialize_tree(buf, sv.manifest, sv.treedef)
+        with obs.span("ckpt.restore", step=step):
+            for meta in sv.metas:
+                for b in range(self.code.k):
+                    total += 1
+                    if not self.store.available(meta.stripe_id, b):
+                        degraded += 1
+                parts.append(self.codec.normal_read(
+                    meta, reader_cluster=reader_cluster))
+            with obs.span("ckpt.assemble", bytes=sv.manifest.total_bytes):
+                buf = b"".join(parts)[:sv.manifest.total_bytes]
+                state = deserialize_tree(buf, sv.manifest, sv.treedef)
         tr1 = self.store.traffic
         report = RestoreReport(
             step=step, total_blocks_read=total, degraded_blocks=degraded,

@@ -15,6 +15,8 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro import obs
+
 
 @dataclasses.dataclass(frozen=True)
 class Manifest:
@@ -49,25 +51,32 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
+def _nbytes(leaf) -> int:
+    return leaf.nbytes if hasattr(leaf, "nbytes") else np.asarray(leaf).nbytes
+
+
 def serialize_tree(tree: Any) -> tuple[bytes, Manifest, Any]:
-    """-> (buffer, manifest, treedef). Leaves in tree-flatten order."""
+    """-> (buffer, manifest, treedef). Leaves in tree-flatten order; a
+    device leaf's copy to the host is counted in `obs.TRANSFERS`."""
     leaves_with_path, treedef = jax.tree_util.tree_flatten_with_path(tree)
     entries = []
     chunks = []
     offset = 0
-    for path, leaf in leaves_with_path:
-        arr = np.asarray(leaf)
-        if arr.dtype == jax.numpy.bfloat16:
-            raw = arr.view(np.uint16).tobytes()
-            dt = "bfloat16"
-        else:
-            raw = arr.tobytes()
-            dt = str(arr.dtype)
-        entries.append((_path_str(path), tuple(arr.shape), dt, offset,
-                        len(raw)))
-        chunks.append(raw)
-        offset += len(raw)
-    buf = b"".join(chunks)
+    with obs.span("ckpt.serialize", bytes=sum(
+            _nbytes(leaf) for _path, leaf in leaves_with_path)):
+        for path, leaf in leaves_with_path:
+            arr = obs.to_host(leaf)
+            if arr.dtype == jax.numpy.bfloat16:
+                raw = arr.view(np.uint16).tobytes()
+                dt = "bfloat16"
+            else:
+                raw = arr.tobytes()
+                dt = str(arr.dtype)
+            entries.append((_path_str(path), tuple(arr.shape), dt, offset,
+                            len(raw)))
+            chunks.append(raw)
+            offset += len(raw)
+        buf = b"".join(chunks)
     return buf, Manifest(tuple(entries), str(treedef), offset), treedef
 
 

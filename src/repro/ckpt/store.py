@@ -27,6 +27,7 @@ import pathlib
 import threading
 from collections.abc import Callable, Iterator
 
+from repro import obs
 from repro.topo import Topology
 
 # Deprecated alias — the cluster/node model's one public name is
@@ -191,12 +192,14 @@ class BlockStore:
         through, no per-block `.tobytes()` staging). Per-entry semantics
         are identical to `put`; only the listener fan-out is batched.
         Returns the number of blocks placed."""
+        entries = list(entries)
         pairs: list[tuple[int, int]] = []
-        for stripe, block, node, data in entries:
-            self._put_nolisten(stripe, block, node, data)
-            pairs.append((stripe, block))
-        if pairs:
-            self._notify_mutation_many(pairs)
+        with obs.span("store.put_many", blocks=len(entries)):
+            for stripe, block, node, data in entries:
+                self._put_nolisten(stripe, block, node, data)
+                pairs.append((stripe, block))
+            if pairs:
+                self._notify_mutation_many(pairs)
         return len(pairs)
 
     def node_of(self, stripe: int, block: int) -> int:
@@ -272,28 +275,30 @@ class BlockStore:
         recorded — and ONE TrafficStats pass at the end instead of a
         per-block `add`. This is the read path under the batched engine:
         a plan group's sources across S stripes are one call here."""
-        nodes: dict[tuple[int, int], int] = {}
-        for key in dict.fromkeys(pairs):
-            node = self._block_node.get(key)
-            if node is None:
-                raise KeyError(key)
-            nodes[key] = node
-        for (stripe, block), node in nodes.items():
-            if node in self._failed:
-                raise NodeFailure(
-                    f"node {node} (stripe {stripe} block {block})")
-        out: dict[tuple[int, int], bytes] = {}
-        inner = cross = 0
-        cluster_of = self.topo.cluster_of
-        for key, node in nodes.items():
-            data = self._payload(key, node)
-            out[key] = data
-            if reader_cluster is not None \
-                    and cluster_of(node) != reader_cluster:
-                cross += len(data)
-            else:
-                inner += len(data)
-        self.traffic.add_many(len(out), inner, cross)
+        keys = dict.fromkeys(pairs)
+        with obs.span("store.get_many", blocks=len(keys)):
+            nodes: dict[tuple[int, int], int] = {}
+            for key in keys:
+                node = self._block_node.get(key)
+                if node is None:
+                    raise KeyError(key)
+                nodes[key] = node
+            for (stripe, block), node in nodes.items():
+                if node in self._failed:
+                    raise NodeFailure(
+                        f"node {node} (stripe {stripe} block {block})")
+            out: dict[tuple[int, int], bytes] = {}
+            inner = cross = 0
+            cluster_of = self.topo.cluster_of
+            for key, node in nodes.items():
+                data = self._payload(key, node)
+                out[key] = data
+                if reader_cluster is not None \
+                        and cluster_of(node) != reader_cluster:
+                    cross += len(data)
+                else:
+                    inner += len(data)
+            self.traffic.add_many(len(out), inner, cross)
         return out
 
     def drop_block(self, stripe: int, block: int):

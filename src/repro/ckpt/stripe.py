@@ -41,6 +41,7 @@ from collections.abc import Callable
 
 import numpy as np
 
+from repro import obs
 from repro.core.codes import Code, make_unilrc
 from repro.core.metrics import locality_metrics
 from repro.core.mttdl import MTTDLParams, code_mttdl_years
@@ -313,7 +314,8 @@ class StripeCodec:
                 self._record_meta(start_stripe + w0 + i, w0 + i,
                                   len(buf), metas)
 
-        self.engine.encode_stream(windows(), land)
+        with obs.span("stripe.write_stream", stripe=start_stripe):
+            self.engine.encode_stream(windows(), land)
         return metas
 
     # -- read planners -------------------------------------------------------
@@ -374,9 +376,11 @@ class StripeCodec:
         """Read the k data blocks; unavailable ones are recovered in the
         same engine flush — one launch per erasure pattern / fast group,
         not one decode per missing block."""
-        finish = self.plan_normal_read(meta, reader_cluster=reader_cluster)
-        self.engine.flush()
-        return finish()
+        with obs.span("stripe.read", stripe=meta.stripe_id):
+            finish = self.plan_normal_read(meta,
+                                           reader_cluster=reader_cluster)
+            self.engine.flush()
+            return finish()
 
     def degraded_read(self, meta: StripeMeta, block: int, *,
                       reader_cluster: int | None = None) -> bytes:

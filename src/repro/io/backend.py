@@ -22,6 +22,7 @@ import abc
 
 import numpy as np
 
+from repro import obs
 from repro.core.codec import DecodePlan, RecoveryPlan
 from repro.core.codes import Code
 from repro.core.gf import gf_matmul
@@ -76,34 +77,66 @@ class Backend(abc.ABC):
 
 
 class KernelBackend(Backend):
-    """JAX/Pallas execution: one kernel launch per batched call."""
+    """JAX/Pallas execution: one kernel launch per batched call.
+
+    Each method copies its host inputs to the device once, and its forced
+    results back, through `obs.to_device` / `obs.to_host`, which count the
+    bytes; each runs in a `repro.backend.<method>` span."""
 
     name = "kernels"
     uses_kernels = True
 
     def encode_many(self, code, data):
-        return np.asarray(self.encode_many_lazy(code, data))
+        S, k, B = data.shape
+        with obs.span("backend.encode_many", to_device=data.nbytes,
+                      from_device=S * code.n * B):
+            return obs.to_host(self.encode_many_lazy(code, data))
 
     def encode_many_lazy(self, code, data):
         from repro.kernels import ops
-        return ops.encode_many(code, data)      # un-forced jax array
+        with obs.span("backend.encode_many_lazy", to_device=data.nbytes,
+                      from_device=0):
+            # un-forced jax array
+            return ops.encode_many(code, obs.to_device(data))
 
     def recover_many(self, plan, stacked):
         from repro.kernels import ops
-        return np.asarray(ops.recover_many(plan, stacked))
+        S, B = stacked[plan.sources[0]].shape
+        with obs.span("backend.recover_many",
+                      to_device=len(plan.sources) * S * B,
+                      from_device=S * B):
+            return obs.to_host(ops.recover_many(
+                plan, _on_device(stacked, plan.sources)))
 
     def apply_decode_many(self, plan, stacked):
         from repro.kernels import ops
-        return {e: np.asarray(v)
-                for e, v in ops.apply_decode_many(plan, stacked).items()}
+        if not plan.erased:
+            return {}
+        S, B = stacked[plan.sources[0]].shape
+        with obs.span("backend.apply_decode_many",
+                      to_device=len(plan.sources) * S * B,
+                      from_device=len(plan.erased) * S * B):
+            rec = ops.apply_decode_many(plan,
+                                        _on_device(stacked, plan.sources))
+            return {e: obs.to_host(v) for e, v in rec.items()}
 
     def delta_terms(self, M, deltas):
         from repro.kernels import ops
-        return np.asarray(ops.apply_matrix(M, deltas))
+        with obs.span("backend.delta_terms", to_device=deltas.nbytes,
+                      from_device=M.shape[0] * deltas.shape[1]):
+            return obs.to_host(ops.apply_matrix(M, obs.to_device(deltas)))
 
     def xor_fold_many(self, stacked):
         from repro.kernels import ops
-        return np.asarray(ops.xor_fold_many(stacked))
+        S, _s, B = stacked.shape
+        with obs.span("backend.xor_fold_many", to_device=stacked.nbytes,
+                      from_device=S * B):
+            return obs.to_host(ops.xor_fold_many(obs.to_device(stacked)))
+
+
+def _on_device(stacked: dict[int, np.ndarray], sources) -> dict:
+    """{source: (S, B)} of the plan's sources, copied to the device."""
+    return {s: obs.to_device(stacked[s]) for s in sources}
 
 
 class NumpyBackend(Backend):

@@ -50,6 +50,7 @@ from typing import Any
 
 import numpy as np
 
+from repro import obs
 from repro.core.codec import decode_plan_cached, plans_for
 from repro.core.codes import Code
 from repro.kernels import ops as kernel_ops
@@ -108,16 +109,11 @@ class _Op:
 class FlushStats:
     """How one flush carved the pending ops into batched backend calls."""
     ops: int = 0
-    read_batches: int = 0
     encode_batches: int = 0
     fast_groups: int = 0       # single-failure block-id groups
     pattern_groups: int = 0    # distinct multi-erasure patterns decoded
-    fast_pairs: int = 0
     multi_pairs: int = 0
-    dropped_pairs: int = 0     # non-strict recovers beyond tolerance
     update_waves: int = 0
-    gateway_folds: int = 0     # remote-cluster pre-fold launches issued
-    aggregated_pairs: int = 0  # pairs served via >= one gateway pre-fold
     launches: int = 0          # kernel launches issued BY this flush
     inner_bytes: int = 0       # store bytes this flush moved, inner tier
     cross_bytes: int = 0       # ... across cluster gateways
@@ -217,10 +213,11 @@ class CodingEngine:
         # FlushStats stay exact when several shard engines flush
         # concurrently (a global before/after snapshot would fold the
         # other shards' work into this flush's numbers).
-        with kernel_ops.launch_scope() as scope, \
+        with obs.span("engine.flush", ops=len(ops_list)), \
+                kernel_ops.launch_scope() as scope, \
                 self.store.traffic.scoped() as tdelta:
             self._run_encodes(by_kind.get("encode", []), stats)
-            self._run_reads(by_kind.get("read", []), stats)
+            self._run_reads(by_kind.get("read", []))
             self._run_recovers(by_kind.get("recover", []), stats)
             self._run_updates(by_kind.get("update", []), stats)
         stats.launches = scope.total
@@ -230,7 +227,7 @@ class CodingEngine:
         return stats
 
     # -- reads ---------------------------------------------------------------
-    def _run_reads(self, ops_list: list[_Op], stats: FlushStats) -> None:
+    def _run_reads(self, ops_list: list[_Op]) -> None:
         by_rc: dict[int | None, list[_Op]] = {}
         for op in ops_list:
             by_rc.setdefault(op.reader_cluster, []).append(op)
@@ -251,7 +248,6 @@ class CodingEngine:
                     except Exception as exc:
                         op.handle._fail(exc)
                 continue
-            stats.read_batches += 1
             for op in group:
                 op.handle._set(got[(op.stripe, op.block)])
 
@@ -278,8 +274,7 @@ class CodingEngine:
                      for s in sources)
 
     def _recover_xor_batch(self, sids: list[int], sources: tuple[int, ...],
-                           rc: int | None, stats: FlushStats
-                           ) -> np.ndarray:
+                           rc: int | None) -> np.ndarray:
         """Gateway-aggregated execution of one XOR-linear plan over a
         stripe batch: remote clusters holding >= 2 sources read them
         locally (inner-tier bytes at THEIR gateway), fold them with one
@@ -310,12 +305,9 @@ class CodingEngine:
                 got = self._gather_sources(gsids, tuple(ss), c)
                 partial = self.backend.xor_fold_many(
                     np.stack([got[s] for s in ss], axis=1))
-                stats.gateway_folds += 1
                 self.store.traffic.add_shipped(int(partial.nbytes))
                 parts.append(partial)
             rec = self.backend.xor_fold_many(np.stack(parts, axis=1))
-            if folds:
-                stats.aggregated_pairs += len(gsids)
             for i, row in zip(poss, rec):
                 results[i] = row
         return np.stack(results)
@@ -375,19 +367,20 @@ class CodingEngine:
             for i0 in range(0, len(sids), self.max_batch_stripes):
                 batch = sids[i0:i0 + self.max_batch_stripes]
                 try:
-                    if aggregate:
-                        rec = self._recover_xor_batch(batch, plan.sources,
-                                                      rc, stats)
-                    else:
-                        stacked = self._gather_sources(batch, plan.sources,
-                                                       rc)
-                        rec = self.backend.recover_many(plan, stacked)
+                    with obs.span("engine.recover", stripes=len(batch),
+                                  sources=len(plan.sources)):
+                        if aggregate:
+                            rec = self._recover_xor_batch(
+                                batch, plan.sources, rc)
+                        else:
+                            stacked = self._gather_sources(
+                                batch, plan.sources, rc)
+                            rec = self.backend.recover_many(plan, stacked)
                 except Exception as exc:
                     fail_pairs([(sid, b) for sid in batch], exc)
                     continue
                 for i, sid in enumerate(batch):
                     resolve(sid, b, rec[i].tobytes(), "fast", ("fast", b))
-                    stats.fast_pairs += 1
 
         for pattern, entries in sorted(slow.items()):
             keys = [(sid, b) for sid, blocks in entries for b in blocks]
@@ -400,7 +393,6 @@ class CodingEngine:
                             op.handle._fail(exc)
                         else:
                             op.handle._set(None)
-                            stats.dropped_pairs += 1
                 continue
             stats.pattern_groups += 1
             # Every member stripe's erased set is a subset of `pattern`,
@@ -412,8 +404,11 @@ class CodingEngine:
                 chunk = entries[i0:i0 + self.max_batch_stripes]
                 sids = [sid for sid, _ in chunk]
                 try:
-                    stacked = self._gather_sources(sids, dplan.sources, rc)
-                    rec = self.backend.apply_decode_many(dplan, stacked)
+                    with obs.span("engine.recover", stripes=len(sids),
+                                  sources=len(dplan.sources)):
+                        stacked = self._gather_sources(sids, dplan.sources,
+                                                       rc)
+                        rec = self.backend.apply_decode_many(dplan, stacked)
                 except Exception as exc:
                     fail_pairs([(sid, b) for sid, blocks in chunk
                                 for b in blocks], exc)
@@ -487,23 +482,33 @@ class CodingEngine:
         prev: tuple[int, Any] | None = None
         with kernel_ops.launch_scope(), self.store.traffic.scoped():
             for view in windows:
-                data = np.ascontiguousarray(view, dtype=np.uint8)
-                if data.ndim != 3 or data.shape[1] != self.code.k:
-                    raise ValueError(
-                        f"encode_stream expects (S, k={self.code.k}, B) "
-                        f"windows, got {data.shape}")
-                if not 1 <= data.shape[0] <= self.max_batch_stripes:
-                    raise ValueError(
-                        f"window of {data.shape[0]} stripes outside "
-                        f"[1, max_batch_stripes={self.max_batch_stripes}]")
-                fut = self.backend.encode_many_lazy(self.code, data)
+                with obs.span("engine.encode_dispatch", stripes=len(view)):
+                    data = np.ascontiguousarray(view, dtype=np.uint8)
+                    if data.ndim != 3 or data.shape[1] != self.code.k:
+                        raise ValueError(
+                            f"encode_stream expects (S, k={self.code.k}, "
+                            f"B) windows, got {data.shape}")
+                    if not 1 <= data.shape[0] <= self.max_batch_stripes:
+                        raise ValueError(
+                            f"window of {data.shape[0]} stripes outside [1, "
+                            f"max_batch_stripes={self.max_batch_stripes}]")
+                    fut = self.backend.encode_many_lazy(self.code, data)
                 if prev is not None:
-                    sink(prev[0], np.asarray(prev[1]))
+                    self._land(sink, *prev)
                 prev = (served, fut)
                 served += 1
             if prev is not None:
-                sink(prev[0], np.asarray(prev[1]))
+                self._land(sink, *prev)
         return served
+
+    @staticmethod
+    def _land(sink, index: int, fut) -> None:
+        """Force one window's codewords to the host (`encode_wait`), then
+        hand them to the sink (`land`)."""
+        with obs.span("engine.encode_wait", stripes=len(fut)):
+            codewords = obs.to_host(fut)
+        with obs.span("engine.land", stripes=len(codewords)):
+            sink(index, codewords)
 
     # -- delta updates -------------------------------------------------------
     def _run_updates(self, ops_list: list[_Op], stats: FlushStats) -> None:
