@@ -25,7 +25,8 @@ from typing import Any
 from repro import obs
 from repro.core.codes import Code
 
-from .serialize import Manifest, deserialize_tree, serialize_tree
+from .serialize import (HOST_PLATFORMS, DeviceStripes, Manifest,
+                        deserialize_tree, serialize_tree, stripes_on_device)
 from .store import BlockStore, NodeFailure
 from .stripe import StripeCodec, choose_code
 
@@ -54,8 +55,13 @@ class _Saved:
 class CheckpointManager:
     def __init__(self, store: BlockStore, code: Code | None = None, *,
                  block_size: int = 1 << 18,
-                 backend=None, use_kernels: bool | None = None):
+                 backend=None, use_kernels: bool | None = None,
+                 host_platforms: frozenset = HOST_PLATFORMS):
+        """`host_platforms`: device platforms whose states `save` copies to
+        host bytes even under the kernel backend, because their device
+        memory is host memory (`serialize.HOST_PLATFORMS`, the CPU)."""
         self.store = store
+        self.host_platforms = host_platforms
         self.code = code or choose_code(store.topo)
         self.block_size = block_size
         # resolve here so the use_kernels= deprecation warning points at
@@ -68,12 +74,14 @@ class CheckpointManager:
         self._next_stripe = 0
 
     # -- save ----------------------------------------------------------------
-    def write_checkpoint(self, buf: bytes, *,
+    def write_checkpoint(self, buf, *,
                          window_stripes: int | None = None) -> list:
         """Stream a raw checkpoint buffer through the fused encode+put
         fast path (`StripeCodec.write_stream`): zero-copy windowed
         ingest, double-buffered kernel dispatch, bulk `put_many`
-        landing. Byte-identical to the per-window `write` path —
+        landing. `buf` is host bytes, or a state on the device
+        (`serialize.DeviceStripes`).
+        Byte-identical to the per-window `write` path —
         `tests/test_ckpt_stream.py` property-tests that on both
         backends. Returns the StripeMeta list; the stripe cursor
         advances just like `save`."""
@@ -84,10 +92,24 @@ class CheckpointManager:
         return metas
 
     def save(self, state: Any, step: int) -> int:
-        """Returns the number of stripes written."""
+        """Returns the number of stripes written. A state whose leaves
+        all live on one device outside `host_platforms`, saved through the
+        kernel backend, is flattened and encoded there (path `device`);
+        any other goes through host bytes (path `host`). Both store the
+        same bytes."""
         with obs.span("ckpt.save", step=step):
-            buf, manifest, treedef = serialize_tree(state)
-            metas = self.write_checkpoint(buf)
+            if self.codec.use_kernels and stripes_on_device(
+                    state, self.code.k * self.block_size,
+                    host_platforms=self.host_platforms):
+                path = "device"
+                data, manifest, treedef = DeviceStripes.of(
+                    state, self.code.k, self.block_size)
+                metas = self.write_checkpoint(data)
+            else:
+                path = "host"
+                buf, manifest, treedef = serialize_tree(state)
+                metas = self.write_checkpoint(buf)
+        obs.count_save(path)
         self._saved[step] = _Saved(metas, manifest, treedef)
         return len(metas)
 

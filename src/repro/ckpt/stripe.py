@@ -52,6 +52,7 @@ from repro.kernels import autotune, ops
 
 from repro.topo import Topology
 
+from .serialize import DeviceStripes
 from .store import BlockStore
 
 
@@ -266,7 +267,7 @@ class StripeCodec:
                 self._record_meta(sid, batch_start + i, len(buf), metas)
         return metas
 
-    def write_stream(self, buf: bytes, *, start_stripe: int = 0,
+    def write_stream(self, buf, *, start_stripe: int = 0,
                      window_stripes: int | None = None) -> list[StripeMeta]:
         """Checkpoint-scale write fast path: same stripes, bytes and
         placement as `write` (byte-identity is property-tested on both
@@ -282,15 +283,30 @@ class StripeCodec:
             `BlockStore.put_many` with a single batched mutation
             notification, not S_w * n `put` round-trips.
 
+        `buf` is host bytes, or a state on the device
+        (`serialize.DeviceStripes`), each of whose windows is laid out
+        there from the leaves when it is dispatched and encoded in
+        place, so only the codewords cross to the host.
+
         Peak extra memory is O(window): at most two windows of codewords
-        (the double buffer) plus one padded tail window are ever live.
+        (the double buffer) plus one padded tail window, or two device
+        windows, are ever live.
         `window_stripes` (default `max_batch_stripes`, clamped to it)
         trades pipeline depth against staging memory — see
         `kernels.autotune.plan_stream_windows`."""
         k, bs = self.code.k, self.block_size
-        stripe_payload = k * bs
-        nstripes = max(1, math.ceil(len(buf) / stripe_payload))
-        arr = np.frombuffer(buf, dtype=np.uint8)
+        if isinstance(buf, DeviceStripes):
+            if (buf.k, buf.block_size) != (k, bs):
+                raise ValueError(f"device stripes of (k, B) = ({buf.k}, "
+                                 f"{buf.block_size}), codec's ({k}, {bs})")
+            nstripes, total, view = buf.stripes, buf.nbytes, buf.window
+        else:
+            total = len(buf)
+            nstripes = max(1, math.ceil(total / (k * bs)))
+            arr = np.frombuffer(buf, dtype=np.uint8)
+
+            def view(w0: int, wn: int):
+                return self._window_view(arr, w0, wn)
         window = min(window_stripes or self.max_batch_stripes,
                      self.max_batch_stripes)
         window = max(1, window)
@@ -299,7 +315,7 @@ class StripeCodec:
 
         def windows():
             for w0 in starts:
-                yield self._window_view(arr, w0, min(window, nstripes - w0))
+                yield view(w0, min(window, nstripes - w0))
 
         def land(idx: int, codewords: np.ndarray) -> None:
             w0 = starts[idx]
@@ -312,7 +328,7 @@ class StripeCodec:
             self.store.put_many(entries)
             for i in range(codewords.shape[0]):
                 self._record_meta(start_stripe + w0 + i, w0 + i,
-                                  len(buf), metas)
+                                  total, metas)
 
         with obs.span("stripe.write_stream", stripe=start_stripe):
             self.engine.encode_stream(windows(), land)

@@ -13,13 +13,16 @@ One interface, two implementations:
 The `CodingEngine` (engine.py) is backend-agnostic: it groups op
 descriptors into batches and hands each batch to exactly one backend
 call, so "which device executes the bytes" is a constructor argument,
-not a branch on every code path. All inputs/outputs are host numpy
-uint8 arrays; the kernel backend owns the device round-trip.
+not a branch on every code path. Inputs and outputs are host numpy
+uint8 arrays, and the kernel backend owns the device round-trip; the one
+exception is `encode_many_lazy`, which also takes stripes already on the
+device and encodes them there.
 """
 from __future__ import annotations
 
 import abc
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -94,7 +97,8 @@ class KernelBackend(Backend):
 
     def encode_many_lazy(self, code, data):
         from repro.kernels import ops
-        with obs.span("backend.encode_many_lazy", to_device=data.nbytes,
+        copied = 0 if isinstance(data, jax.Array) else data.nbytes
+        with obs.span("backend.encode_many_lazy", to_device=copied,
                       from_device=0):
             # un-forced jax array
             return ops.encode_many(code, obs.to_device(data))

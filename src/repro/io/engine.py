@@ -48,6 +48,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -463,7 +464,9 @@ class CodingEngine:
         path, fused with store landing.
 
         `windows` yields (S_w, k, B) uint8 arrays (views are fine —
-        nothing is copied here), each with S_w <= max_batch_stripes;
+        nothing is copied here), each with S_w <= max_batch_stripes:
+        host arrays, or `jax.Array`s already on the device, which stay
+        there (the backend encodes them in place);
         `sink(index, codewords)` receives every window's forced (S_w,
         n, B) result, in order. The pipeline overlap: window w+1's
         encode is DISPATCHED (`Backend.encode_many_lazy` — un-forced
@@ -483,11 +486,14 @@ class CodingEngine:
         with kernel_ops.launch_scope(), self.store.traffic.scoped():
             for view in windows:
                 with obs.span("engine.encode_dispatch", stripes=len(view)):
-                    data = np.ascontiguousarray(view, dtype=np.uint8)
-                    if data.ndim != 3 or data.shape[1] != self.code.k:
+                    data = (view if isinstance(view, jax.Array) else
+                            np.ascontiguousarray(view, dtype=np.uint8))
+                    if (data.dtype != np.uint8 or data.ndim != 3
+                            or data.shape[1] != self.code.k):
                         raise ValueError(
                             f"encode_stream expects (S, k={self.code.k}, "
-                            f"B) windows, got {data.shape}")
+                            f"B) uint8 windows, got {data.dtype} "
+                            f"{data.shape}")
                     if not 1 <= data.shape[0] <= self.max_batch_stripes:
                         raise ValueError(
                             f"window of {data.shape[0]} stripes outside [1, "

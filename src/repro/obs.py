@@ -12,6 +12,11 @@ the device, under `to_device` and `from_device`. `to_device` and `to_host`
 make the copies and count them; `_count` is the one mutation point, under
 a lock (the sharded front-end flushes from a worker pool); `snapshot()`
 copies the totals, so a caller takes a delta.
+
+SAVE_PATHS counts checkpoint saves by the path their bytes took:
+`device` (flattened and encoded on the device, only codewords come home)
+or `host` (flattened to host bytes first), for a job to read in-process
+with no profiler running. `count_save` is its mutation point.
 """
 from __future__ import annotations
 
@@ -22,6 +27,7 @@ import jax
 import numpy as np
 
 TRANSFERS: collections.Counter = collections.Counter()
+SAVE_PATHS: collections.Counter = collections.Counter()
 _LOCK = threading.Lock()
 
 
@@ -32,6 +38,11 @@ def span(name: str, **attrs) -> jax.profiler.TraceAnnotation:
 def _count(direction: str, nbytes: int) -> None:
     with _LOCK:
         TRANSFERS[direction] += int(nbytes)
+
+
+def count_save(path: str) -> None:
+    with _LOCK:
+        SAVE_PATHS[path] += 1
 
 
 def snapshot() -> dict[str, int]:

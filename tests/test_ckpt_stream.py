@@ -16,13 +16,21 @@ staging memory, and the `put_many` batched mutation-listener protocol
 the landing path rides (one notification per window, hot-block cache
 invalidation stays exact).
 """
+import glob
 import math
 import tracemalloc
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
+from repro import obs
 from repro.ckpt import BlockStore, CheckpointManager, DiskBlockStore
+from repro.ckpt import serialize
+from repro.ckpt.serialize import (DeviceStripes, serialize_tree,
+                                  stripes_on_device)
 from repro.ckpt.stripe import StripeCodec
 from repro.core.codes import make_unilrc
 from repro.io.cache import HotBlockCache
@@ -268,3 +276,249 @@ def test_frontend_checkpoint_write_background(shards):
     assert codec.read_all(metas)[:len(buf)] == buf
     if shards > 1:
         fe.close()
+
+
+# ---------------------------------------------------------------------------
+# device path: a state on the device is striped and encoded there
+# ---------------------------------------------------------------------------
+
+# host platforms of a manager that takes the CPU for an accelerator, as a
+# TPU is: a state on it takes the device path
+ACCELERATOR = frozenset()
+
+LEAF_CASES = [
+    ("float32", ((serialize.ROW // 4) * 600 + 5,)),   # many rows, a tail
+    ("float32", (300, 128)),
+    ("bfloat16", (1000, 3)),
+    ("int32", (7,)),                                  # under one row
+    ("int32", ()),                                    # 0-d
+    ("uint16", (513, 256)),
+    ("int8", (33,)),
+    ("bool", (130,)),
+]
+
+
+def _host_leaf(dtype, shape, seed=len(LEAF_CASES)):
+    """Every bit pattern, NaNs and subnormals among them."""
+    rng = np.random.default_rng(seed)
+    bits = rng.integers(0, 256, int(np.prod(shape)) * np.dtype(
+        jnp.dtype(dtype)).itemsize, dtype=np.uint8)
+    if dtype == "bool":
+        return (bits & 1).astype(bool).reshape(shape)
+    return bits.view(jnp.dtype(dtype)).reshape(shape)
+
+
+@pytest.mark.parametrize("dtype,shape", LEAF_CASES)
+def test_leaf_bytes_are_numpys_bytes(dtype, shape):
+    """Every bit pattern comes out as numpy's `tobytes` has it."""
+    host = _host_leaf(dtype, shape)
+    got = np.asarray(jax.jit(serialize.leaf_bytes)(jnp.asarray(host)))
+    assert got.dtype == np.uint8
+    assert got.tobytes() == host.tobytes()
+
+
+@pytest.mark.parametrize("parts", [5, 13])
+@pytest.mark.parametrize("dtype,shape", LEAF_CASES)
+def test_windows_cut_anywhere_in_a_leaf_are_its_bytes(dtype, shape, parts):
+    """One leaf laid out as stripes of 3 blocks of about 1/parts of it,
+    two stripes a window: stripe and window boundaries fall mid-row and
+    mid-element, and each window holds the leaf's bytes there,
+    zero-padded after the last."""
+    host = _host_leaf(dtype, shape)
+    block_size = host.nbytes // parts + 1
+    stripes, manifest, _ = DeviceStripes.of({"x": jnp.asarray(host)}, 3,
+                                            block_size)
+    size = 3 * block_size
+    want = np.zeros(stripes.stripes * size, np.uint8)
+    want[:host.nbytes] = np.frombuffer(host.tobytes(), np.uint8)
+    for w0 in range(0, stripes.stripes, 2):
+        wn = min(2, stripes.stripes - w0)
+        got = stripes.window(w0, wn)
+        assert got.shape == (wn, 3, block_size)
+        assert np.array_equal(np.asarray(got).reshape(-1),
+                              want[w0 * size:(w0 + wn) * size])
+
+
+@pytest.mark.parametrize("shape,a,b", [((100_000,), 0, 400_000),
+                                       ((100_000,), 4_099, 399_997),
+                                       ((40, 70_000), 1_000, 11_199_997),
+                                       ((3, 30_000_000), 5, 360_000_000)])
+def test_row_blocks_cover_the_bytes_in_aligned_blocks(shape, a, b):
+    """A leaf's bytes [a, b) as blocks of rows that cover them end to
+    end: each block CHUNK bytes or one row, whichever is more, plus what
+    rounding its ends to whole tiles of rows adds, and every cut on a
+    tile's rows unless a row is too wide for CHUNK to hold a tile."""
+    leaf = jax.ShapeDtypeStruct(shape, jnp.float32)
+    rows, row_bytes = serialize._rows(leaf)
+    align = serialize._align(leaf)
+    if serialize.CHUNK // row_bytes < align:
+        align = 1
+    blocks = serialize._row_blocks(leaf, a, b)
+    assert blocks[0][0] * row_bytes <= a and blocks[-1][1] * row_bytes >= b
+    assert all(x[1] == y[0] for x, y in zip(blocks, blocks[1:]))
+    for r0, r1 in blocks:
+        assert r0 % align == 0 and (r1 % align == 0 or r1 == rows)
+        assert 0 < (r1 - r0) * row_bytes <= max(serialize.CHUNK, row_bytes)
+    assert blocks[0][0] > a // row_bytes - align
+    assert blocks[-1][1] < -(-b // row_bytes) + align
+
+
+def mixed_tree(uint8_bytes: int) -> dict:
+    """uint8, bfloat16, float32 and int32 leaves and a 0-d one; 22,007
+    bytes besides the `d_u8` leaf. The 3 bytes in front put every later
+    stripe boundary off the elements' and the rows' boundaries. The
+    float32 and bfloat16 leaves are whole rows of `leaf_bytes` plus a
+    tail."""
+    rng = np.random.default_rng(uint8_bytes)
+    return {
+        "_u8_odd": jnp.asarray(rng.integers(0, 256, 3), jnp.uint8),
+        "a_bf16": jnp.asarray(rng.standard_normal((50, 100)),
+                              jnp.bfloat16),
+        "b_f32": jnp.asarray(rng.standard_normal(2000) * 1e3, jnp.float32),
+        "c_i32": jnp.asarray(rng.integers(-2**31, 2**31, 1000), jnp.int32),
+        "d_u8": jnp.asarray(rng.integers(0, 256, uint8_bytes), jnp.uint8),
+        "e_step": jnp.int32(-7),
+    }
+
+
+# 4 stripes exactly, and 4 with a padded tail
+TREES = {"exact": 4 * stripe_payload - 22_007,
+         "padded_tail": 3 * stripe_payload - 22_007 + 1000}
+
+
+def _stores_identical(mgr_a, store_a, mgr_b, store_b, step):
+    metas = mgr_a.stripes_of(step)
+    assert metas == mgr_b.stripes_of(step)
+    for m in metas:
+        for b in range(CODE.n):
+            assert store_a.get(m.stripe_id, b) == store_b.get(m.stripe_id, b)
+            assert store_a.node_of(m.stripe_id, b) \
+                == store_b.node_of(m.stripe_id, b)
+
+
+@pytest.mark.parametrize("window", [1, 2, 3])
+@pytest.mark.parametrize("tree", sorted(TREES))
+def test_device_stripes_store_what_host_bytes_store(tree, window):
+    """Device stripes and host bytes of one tree: the same manifest, the
+    same bytes window by window, and every block of every stripe stored
+    the same, on the same node."""
+    state = mixed_tree(TREES[tree])
+    assert stripes_on_device(state, stripe_payload,
+                             host_platforms=ACCELERATOR)
+    data, manifest, treedef = DeviceStripes.of(state, CODE.k, BS)
+    buf, manifest_h, treedef_h = serialize_tree(state)
+    assert (manifest, treedef) == (manifest_h, treedef_h)
+    assert data.stripes == 4 and data.nbytes == len(buf)
+    padded = np.zeros(4 * stripe_payload, np.uint8)
+    padded[:len(buf)] = np.frombuffer(buf, np.uint8)
+    for w0 in range(0, 4, window):
+        wn = min(window, 4 - w0)
+        got = data.window(w0, wn)
+        assert got.shape == (wn, CODE.k, BS)
+        assert np.array_equal(np.asarray(got).reshape(-1), padded[
+            w0 * stripe_payload:(w0 + wn) * stripe_payload])
+    mgrs = [CheckpointManager(BlockStore(Topology(4, 6)), CODE,
+                              block_size=BS) for _ in range(2)]
+    metas = mgrs[0].write_checkpoint(data, window_stripes=window)
+    metas_h = mgrs[1].write_checkpoint(buf, window_stripes=window)
+    assert metas == metas_h
+    assert stripes_identical(mgrs[0].store, mgrs[1].store, metas, CODE.n)
+
+
+@pytest.mark.parametrize("tree", sorted(TREES))
+def test_device_path_save_matches_host_path_and_restores(tree):
+    """`save` of a device state and of its numpy copy: identical stores,
+    placement, metas and manifests; the restore is bit-exact."""
+    state = mixed_tree(TREES[tree])
+    runs = []
+    for leaves in (state, jax.tree_util.tree_map(np.asarray, state)):
+        store = BlockStore(Topology(4, 6))
+        mgr = CheckpointManager(store, CODE, block_size=BS,
+                                host_platforms=ACCELERATOR)
+        before = dict(obs.SAVE_PATHS)
+        mgr.save(leaves, step=1)
+        runs.append((mgr, store, {p: obs.SAVE_PATHS[p] - before.get(p, 0)
+                                  for p in ("device", "host")}))
+    (mgr_d, store_d, took_d), (mgr_h, store_h, took_h) = runs
+    assert took_d == {"device": 1, "host": 0}
+    assert took_h == {"device": 0, "host": 1}
+    _stores_identical(mgr_d, store_d, mgr_h, store_h, 1)
+    assert mgr_d._saved[1].manifest == mgr_h._saved[1].manifest
+    back, _ = mgr_d.restore(1)
+    for a, b in zip(jax.tree_util.tree_leaves(state),
+                    jax.tree_util.tree_leaves(back)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def test_only_a_row_or_a_stripe_past_the_limit_keeps_the_host_path(
+        monkeypatch):
+    """A leaf of FLAT_LIMIT bytes or more is flattened on the device a
+    chunk at a time; a row of more than FLAT_LIMIT / 4 bytes, or a stripe
+    of FLAT_LIMIT or more, is not: XLA would index a flat buffer past
+    int32."""
+    monkeypatch.setattr(serialize, "FLAT_LIMIT", 8192)
+
+    def device(tree, stripe_bytes=4096):
+        return stripes_on_device(tree, stripe_bytes,
+                                 host_platforms=ACCELERATOR)
+    assert device({"w": jnp.zeros(100, jnp.float32)})
+    assert device({"w": jnp.zeros(4096, jnp.float32)})       # 16 KiB
+    assert device({"w": jnp.zeros((8, 512), jnp.float32)})   # rows of 2 KiB
+    assert not device({"w": jnp.zeros((2, 520), jnp.float32)})
+    assert not device({"w": jnp.zeros(100, jnp.float32)}, 8192)
+
+
+def _jax_state():
+    return {"w": jnp.arange(3000, dtype=jnp.float32), "step": jnp.int32(3)}
+
+
+# (state, backend, host platforms, the path its save takes)
+PATH_CASES = {
+    "jax_leaves": (_jax_state, "kernels", ACCELERATOR, "device"),
+    "numpy_leaves": (lambda: jax.tree_util.tree_map(np.asarray,
+                                                    _jax_state()),
+                     "kernels", ACCELERATOR, "host"),
+    "python_scalar_leaf": (lambda: {**_jax_state(), "step": 3},
+                           "kernels", ACCELERATOR, "host"),
+    "numpy_backend": (_jax_state, "numpy", ACCELERATOR, "host"),
+    "jax_leaves_in_host_memory": (_jax_state, "kernels",
+                                  serialize.HOST_PLATFORMS, "host"),
+}
+
+
+@pytest.fixture(scope="module")
+def save_paths(tmp_path_factory):
+    """Each case's save, in PATH_CASES order inside one trace: the
+    SAVE_PATHS counts it added, and the `path` attribute of every
+    `repro.ckpt.serialize` span in start order."""
+    log_dir = str(tmp_path_factory.mktemp("paths"))
+    counted = {}
+    with jax.profiler.trace(log_dir):
+        for name, (make, backend, host, _path) in PATH_CASES.items():
+            mgr = CheckpointManager(BlockStore(Topology(4, 6)), CODE,
+                                    block_size=BS, backend=backend,
+                                    host_platforms=host)
+            before = dict(obs.SAVE_PATHS)
+            mgr.save(make(), step=0)
+            counted[name] = {p: n - before.get(p, 0)
+                             for p, n in obs.SAVE_PATHS.items()
+                             if n != before.get(p, 0)}
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    events = sorted((ev.start_ns, dict(ev.stats)["path"])
+                    for plane in ProfileData.from_file(path).planes
+                    for line in plane.lines for ev in line.events
+                    if ev.name == "repro.ckpt.serialize")
+    return counted, [p for _start, p in events]
+
+
+@pytest.mark.parametrize("case", list(PATH_CASES))
+def test_save_takes_the_path_its_input_allows(save_paths, case):
+    """Device leaves under the kernel backend take the device path;
+    numpy leaves, a Python scalar leaf, the numpy backend or leaves whose
+    device memory is host memory keep host bytes. Read from the counter
+    and from the span's attribute."""
+    counted, spans = save_paths
+    path = PATH_CASES[case][3]
+    assert counted[case] == {path: 1}
+    assert spans[list(PATH_CASES).index(case)] == path

@@ -37,12 +37,18 @@ def _state():
 
 @pytest.fixture(scope="module")
 def profiled(tmp_path_factory):
-    """A 30-of-42 save of two stripes at 64 KiB blocks, then a restore
-    with the node holding stripe 0's block 0 down, inside one trace;
-    the transfer counters' deltas over each; the manager and its store."""
+    """A 30-of-42 save of two stripes at 64 KiB blocks, by a manager that
+    takes the CPU for an accelerator, so by the device path, then a
+    restore with the node holding stripe 0's block 0 down, inside one
+    trace; after it, the same state saved by a manager that keeps CPU
+    states on the host path; the transfer counters' deltas over each; the
+    managers and the first one's store."""
     code = make_unilrc(1, 6)
     store = BlockStore(Topology(6, 8))
-    mgr = CheckpointManager(store, code, block_size=BLOCK)
+    mgr = CheckpointManager(store, code, block_size=BLOCK,
+                            host_platforms=frozenset())
+    host_mgr = CheckpointManager(BlockStore(Topology(6, 8)), code,
+                                 block_size=BLOCK)
     state = _state()
     jax.block_until_ready(state)
     log_dir = str(tmp_path_factory.mktemp("trace"))
@@ -53,11 +59,14 @@ def profiled(tmp_path_factory):
         store.fail_node(store.node_of(mgr.stripes_of(3)[0].stripe_id, 0))
         back, report = mgr.restore(3)
         x2 = obs.snapshot()
+    host_mgr.save(state, step=4)
+    x3 = obs.snapshot()
     path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
-    deltas = [{d: b[d] - a[d] for d in a} for a, b in ((x0, x1), (x1, x2))]
+    deltas = [{d: b[d] - a[d] for d in a}
+              for a, b in ((x0, x1), (x1, x2), (x2, x3))]
     return dict(data=ProfileData.from_file(path), deltas=deltas,
-                mgr=mgr, store=store, code=code, state=state, back=back,
-                report=report)
+                mgr=mgr, host_mgr=host_mgr, store=store, code=code,
+                state=state, back=back, report=report)
 
 
 def test_every_span_is_in_the_trace(profiled):
@@ -81,15 +90,23 @@ def test_the_restore_was_degraded_and_exact(profiled):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_save_transfers_are_the_bytes_of_the_shapes(profiled):
+@pytest.mark.parametrize("path", ["device", "host"])
+def test_save_transfers_are_the_bytes_of_the_shapes(profiled, path):
+    """A state on the device is striped and encoded there: only the
+    codewords come home. By the host path the leaves come to the host,
+    the stripes go to the device and the codewords come back."""
     code = profiled["code"]
     leaves = sum(x.nbytes for x in jax.tree_util.tree_leaves(
         profiled["state"]))
-    stripes = len(profiled["mgr"].stripes_of(3))
+    mgr, step, delta = {"device": ("mgr", 3, 0),
+                        "host": ("host_mgr", 4, 2)}[path]
+    stripes = len(profiled[mgr].stripes_of(step))
     assert stripes == -(-leaves // (code.k * BLOCK)) == 2
-    assert profiled["deltas"][0] == {
-        "to_device": stripes * code.k * BLOCK,
-        "from_device": leaves + stripes * code.n * BLOCK}
+    expect = {"to_device": 0, "from_device": stripes * code.n * BLOCK}
+    if path == "host":
+        expect = {"to_device": stripes * code.k * BLOCK,
+                  "from_device": leaves + stripes * code.n * BLOCK}
+    assert profiled["deltas"][delta] == expect
 
 
 def test_restore_transfers_are_the_bytes_of_the_shapes(profiled):

@@ -109,3 +109,50 @@ def test_two_encode_windows_fit_in_hbm(one_chip, scheme):
     # half the HBM at most: the rest holds the state being saved
     assert 2 * per_window < V5E_HBM // 2
 
+
+def test_a_device_save_of_more_than_half_the_hbm_fits(one_chip):
+    """A save's device path for a 9.7 GB state, more than half of a v5e's
+    HBM: Phi-4-mini's embedding (bf16, and an f32 moment of 2.46 GB, one
+    leaf past 2^31 bytes) and 6 layers of bf16 weights with both of
+    Adam's f32 moments. Each encode window is laid out from the leaves
+    on its own, so the save needs the state plus O(window): two windows
+    of stripes and of codewords, and one flatten's temporaries. Compiled
+    for the window with the most row blocks and for one inside the
+    large leaf."""
+    from repro.ckpt import serialize
+    d, ff, q, kv, vocab = 3072, 8192, 3072, 1024, 200_064
+    mats = [(d, q), (d, kv), (d, kv), (q, d), (d, ff), (d, ff), (ff, d)]
+    state = {"embed": _spec((vocab, d), jnp.bfloat16, one_chip),
+             "embed_mu": _spec((vocab, d), jnp.float32, one_chip)}
+    for layer in range(6):
+        for i, shape in enumerate(mats):
+            state[f"{layer}.{i}"] = _spec(shape, jnp.bfloat16, one_chip)
+            state[f"{layer}.{i}.mu"] = _spec(shape, jnp.float32, one_chip)
+            state[f"{layer}.{i}.nu"] = _spec(shape, jnp.float32, one_chip)
+    code = make_unilrc(*CODES["180-of-210"])
+    window = autotune.plan_stream_windows(code.k, code.n, BLOCK)
+    stripes, manifest, _ = serialize.DeviceStripes.of(state, code.k, BLOCK)
+    total = manifest.total_bytes
+    assert total > V5E_HBM // 2
+    assert max(e[4] for e in manifest.entries) > serialize.FLAT_LIMIT
+    plans = {w0: stripes.plan(w0, window)
+             for w0 in range(0, stripes.stripes - window + 1, window)}
+    busiest = max(plans, key=lambda w0: sum(len(blocks) for _pos, blocks
+                                            in plans[w0][1]))
+    embed_mu = next(w0 for w0, (leaves, _) in plans.items()
+                    if len(leaves) == 1)
+    flatten = 0
+    for w0 in (busiest, embed_mu):
+        leaves, plan = plans[w0]
+        mem = serialize.flatten_window.lower(
+            leaves, plan=plan, stripes=window, k=code.k,
+            block_size=BLOCK).compile().memory_analysis()
+        assert mem.output_size_in_bytes >= window * code.k * BLOCK
+        flatten = max(flatten, mem.output_size_in_bytes
+                      + mem.temp_size_in_bytes)
+    encode = _compile(lambda x: ops.encode_many(code, x, interpret=False),
+                      _spec((window, code.k, BLOCK), jnp.uint8, one_chip)
+                      ).memory_analysis()
+    per_window = (encode.argument_size_in_bytes
+                  + encode.output_size_in_bytes + encode.temp_size_in_bytes)
+    assert total + 2 * (flatten + per_window) < V5E_HBM
