@@ -14,13 +14,20 @@ code's local groups, not from the program:
                                  blocks, rebuilt from k sources.
 
 The check holds every restore's leaves on the device, bit for bit, to the
-state saved.
+state saved, and the set-up save, sampled before the failure while every
+node is up, to the plain reference as `save_loop` holds its saves
+(`save_check`): `stored_bytes_wrong`, `blocks_missing`, `placement_faults`.
+A parity that no repair reads is seen only there.
 """
 from __future__ import annotations
+
+import sys
+import time
 
 import jax
 
 from bench.harness import payload as pl
+from bench.harness import save_check
 from bench.harness.kind import Base, failed, launches
 from bench.harness.record import Check, Op
 from bench.harness.trace import span
@@ -35,9 +42,17 @@ class Kind(Base):
             self.state0))
         self.specs = [(x.shape, x.dtype) for x in
                       jax.tree_util.tree_leaves(self.state0)]
+        self.sampled = save_check.Samples(self.store, self.code, self.B,
+                                          self.rng)
         with span("save"):
             self.mgr.save(self.state0, step=0)
         metas = self.mgr.stripes_of(0)
+        self.stripes = len(metas)
+        t0 = time.perf_counter()
+        with span("sample"):
+            self.sampled.take(0, metas, drop=False)
+        print(f"bench: set-up save sampled in {time.perf_counter() - t0:.3f}"
+              " s", file=sys.stderr)
         self.fail(metas[0].stripe_id)
         self.repair = self._repair(metas)
         self.wrong: list = []
@@ -113,8 +128,14 @@ class Kind(Base):
 
     def check(self, run) -> None:
         wrong = sum(int(w) for ws in self.wrong for w in ws)
+        flat = pl.flat_bytes(self.state0, self.stripes * self.code.k * self.B)
+        bad, faults = self.sampled.wrong(flat, None, self.A, self.groups,
+                                         self.cfg["nodes_per_cluster"])
         run.checks += [
             Check("restores_failed", sum(op.done is None for op in run.ops),
                   0),
             Check("restores_with_wrong_leaves", self.bad_shape, 0),
-            Check("restored_words_wrong", wrong, 0)]
+            Check("restored_words_wrong", wrong, 0),
+            Check("blocks_missing", self.sampled.missing, 0),
+            Check("stored_bytes_wrong", bad, 0),
+            Check("placement_faults", faults, 0)]

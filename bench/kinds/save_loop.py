@@ -9,7 +9,7 @@ The check holds every save, the warm-up's too, to the plain reference:
   stored_bytes_wrong          every block of every stripe at the first,
                               the last and `SAVE_CHECK_COLUMNS` columns
                               drawn from the seed, taken as each save is
-                              dropped or at the end;
+                              dropped or at the end (`save_check`);
   stored_bytes_wrong_in_full  every byte of every block of the saves still
                               held at the end: data against the state's
                               bytes, parities against the reference's
@@ -25,14 +25,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.harness import payload as pl
-from bench.harness import reference
+from bench.harness import reference, save_check
 from bench.harness.kind import Base, failed, launches
 from bench.harness.record import Check, Op
 from bench.harness.trace import span
-
-# Columns of every stored block that the sampled check compares with the
-# reference: the first, the last and this many more, drawn from the seed.
-SAVE_CHECK_COLUMNS = 2048
 
 
 @jax.jit
@@ -64,11 +60,8 @@ class Kind(Base):
         self.stripes = math.ceil(self.nbytes / (self.code.k * self.B))
         self.keep = self.traffic["keep_saves"]
         self.live: list[tuple[int, list]] = []     # (xor byte, metas)
-        self.samples: list[tuple[int, int, np.ndarray, list[int]]] = []
-        self.missing = 0
-        self.cols = np.unique(np.concatenate([
-            [0, self.B - 1],
-            self.rng.integers(0, self.B, SAVE_CHECK_COLUMNS)]))
+        self.sampled = save_check.Samples(self.store, self.code, self.B,
+                                          self.rng)
         self.step = 0
 
     def _save(self) -> None:
@@ -83,34 +76,7 @@ class Kind(Base):
     def _retire(self) -> None:
         with span("sample_and_drop"):
             while len(self.live) > self.keep:
-                self._sample(*self.live.pop(0), drop=True)
-
-    def _blocks(self, sid: int) -> tuple[list, list[int]]:
-        """A stripe's blocks as the store holds them, and their nodes; a
-        block the store does not hold is None, on node -1 - b."""
-        blocks, nodes = [], []
-        for b in range(self.code.n):
-            try:
-                blocks.append(np.frombuffer(self.store.get(sid, b), np.uint8))
-                nodes.append(self.store.node_of(sid, b))
-            except (KeyError, IndexError):
-                blocks.append(None)
-                nodes.append(-1 - b)
-        return blocks, nodes
-
-    def _sample(self, c: int, metas: list, drop: bool) -> None:
-        """Keep the checked columns of a save's blocks, then maybe drop
-        them."""
-        empty = np.zeros(self.cols.size, np.uint8)
-        for i, meta in enumerate(metas):
-            blocks, nodes = self._blocks(meta.stripe_id)
-            self.missing += sum(x is None for x in blocks)
-            self.samples.append((c, i, np.stack(
-                [empty if x is None else x[self.cols] for x in blocks]),
-                nodes))
-            if drop:
-                for b in range(self.code.n):
-                    self.store.drop_block(meta.stripe_id, b)
+                self.sampled.take(*self.live.pop(0), drop=True)
 
     def warmup(self) -> None:
         self._save()
@@ -145,7 +111,8 @@ class Kind(Base):
         for c, metas in self.live:
             saved = saved_stripes(flat, low, np.uint8(c))
             for i, meta in enumerate(metas):
-                blocks, _ = self._blocks(meta.stripe_id)
+                blocks, _ = save_check.blocks(self.store, self.code.n,
+                                              meta.stripe_id)
                 stored = np.stack([np.zeros(self.B, np.uint8) if x is None
                                    else x for x in blocks])
                 wrong += int(stripe_bytes_wrong(a_bits, stored, saved, i))
@@ -153,27 +120,19 @@ class Kind(Base):
 
     def check(self, run) -> None:
         for c, metas in self.live:
-            self._sample(c, metas, drop=False)
-        k, B = self.code.k, self.B
-        size = self.stripes * k * B
+            self.sampled.take(c, metas, drop=False)
+        size = self.stripes * self.code.k * self.B
         flat = pl.flat_bytes(self.state0, size)
         low = pl.low_bytes(self.state0, size)
-        bad = faults = 0
-        for c, i, got, nodes in self.samples:
-            pos = (i * k + np.arange(k))[:, None] * B + self.cols[None, :]
-            data = flat[pos] ^ (low[pos] * np.uint8(c))
-            expect = np.concatenate([data, reference.encode_columns(
-                self.A, data)])
-            bad += int(np.count_nonzero(expect != got))
-            faults += reference.placement_faults(
-                nodes, self.groups, self.cfg["nodes_per_cluster"])
+        bad, faults = self.sampled.wrong(flat, low, self.A, self.groups,
+                                         self.cfg["nodes_per_cluster"])
         del self.state0                  # room on the device for the bytes
         in_full = self._in_full(flat, low)
         run.checks += [
             Check("saves_failed", sum(op.done is None for op in run.ops), 0),
             Check("stripes_unchecked", self.step * self.stripes
-                  - len(self.samples), 0),
-            Check("blocks_missing", self.missing, 0),
+                  - len(self.sampled.samples), 0),
+            Check("blocks_missing", self.sampled.missing, 0),
             Check("stored_bytes_wrong", bad, 0),
             Check("stored_bytes_wrong_in_full", in_full, 0),
             Check("placement_faults", faults, 0)]

@@ -9,11 +9,13 @@ from bench.harness import controls
 from .tiny import run_tiny
 
 # bf16_accumulate lowers the precision of the MXU's GF(2^8) sums where the
-# cell's coding runs on gf_bitmatmul; an XOR fold has no precision to
-# lower, so drop_source breaks the bit-exact repair instead.
+# cell's coding runs on gf_bitmatmul: the encode of every save, the set-up
+# save of a restore cell too; an XOR fold has no precision to lower, so
+# drop_source breaks the bit-exact repair instead.
 CONTROLS = {"wide-save": "bf16_accumulate",
             "wide-restore-1down": "drop_source",
-            "wide-restore-cluster-down": "bf16_accumulate"}
+            "wide-restore-cluster-down": "bf16_accumulate",
+            "wide-restore-healthy": "bf16_accumulate"}
 
 
 @pytest.mark.parametrize(("cell_name", "control"), sorted(CONTROLS.items()))
