@@ -15,6 +15,13 @@ framework (DESIGN.md §2):
 The manager survives losing any `d-1` nodes *or one full cluster* per
 stripe (Theorem 3.2). Restores are deterministic bytes — the restored
 state is bit-identical to what was saved, which tests assert.
+
+With `checksum="crc32c"` (HDFS's default: CRC32C per 512 bytes) every
+saved block carries its CRCs, and a restore checks every block it reads
+before using it: a block found corrupt is rebuilt like a lost one and
+listed in `RestoreReport.corrupt_blocks`, and left in the store as it is
+(an HDFS reader reports a bad replica, it does not rewrite it). More
+than the code can rebuild raises `CorruptionError`.
 """
 from __future__ import annotations
 
@@ -39,6 +46,7 @@ class RestoreReport:
     cross_cluster_bytes: int
     inner_cluster_bytes: int
     wall_seconds: float
+    corrupt_blocks: tuple = ()    # (stripe, block) pairs that failed CRCs
 
     @property
     def degraded(self) -> bool:
@@ -56,10 +64,13 @@ class CheckpointManager:
     def __init__(self, store: BlockStore, code: Code | None = None, *,
                  block_size: int = 1 << 18,
                  backend=None, use_kernels: bool | None = None,
-                 host_platforms: frozenset = HOST_PLATFORMS):
+                 host_platforms: frozenset = HOST_PLATFORMS,
+                 checksum: str | None = None):
         """`host_platforms`: device platforms whose states `save` copies to
         host bytes even under the kernel backend, because their device
-        memory is host memory (`serialize.HOST_PLATFORMS`, the CPU)."""
+        memory is host memory (`serialize.HOST_PLATFORMS`, the CPU).
+        `checksum`: the store's block checksums (`StripeCodec`): None,
+        or "crc32c" per 512 bytes."""
         self.store = store
         self.host_platforms = host_platforms
         self.code = code or choose_code(store.topo)
@@ -69,7 +80,8 @@ class CheckpointManager:
         from repro.io.backend import resolve_backend
         self.codec = StripeCodec(
             self.code, store, block_size=block_size,
-            backend=resolve_backend(backend, use_kernels=use_kernels))
+            backend=resolve_backend(backend, use_kernels=use_kernels),
+            checksum=checksum)
         self._saved: dict[int, _Saved] = {}
         self._next_stripe = 0
 
@@ -133,7 +145,8 @@ class CheckpointManager:
                 reader_cluster: int | None = None
                 ) -> tuple[Any, RestoreReport]:
         """Restore state; any unavailable block is degraded-read from its
-        local group (zero cross-cluster traffic under UniLRC placement)."""
+        local group (zero cross-cluster traffic under UniLRC placement),
+        and so is any block that fails its checksums."""
         if step is None:
             step = self.latest_step()
         if step is None or step not in self._saved:
@@ -145,14 +158,17 @@ class CheckpointManager:
         degraded = 0
         total = 0
         parts = []
+        corrupt: list[tuple[int, int]] = []
         with obs.span("ckpt.restore", step=step):
             for meta in sv.metas:
                 for b in range(self.code.k):
                     total += 1
                     if not self.store.available(meta.stripe_id, b):
                         degraded += 1
-                parts.append(self.codec.normal_read(
-                    meta, reader_cluster=reader_cluster))
+                part, stats = self.codec.read_stripe(
+                    meta, reader_cluster=reader_cluster)
+                parts.append(part)
+                corrupt += stats.corrupt
             with obs.span("ckpt.assemble", bytes=sv.manifest.total_bytes):
                 buf = b"".join(parts)[:sv.manifest.total_bytes]
                 state = deserialize_tree(buf, sv.manifest, sv.treedef)
@@ -161,7 +177,8 @@ class CheckpointManager:
             step=step, total_blocks_read=total, degraded_blocks=degraded,
             cross_cluster_bytes=tr1.cross_bytes - tr0.cross_bytes,
             inner_cluster_bytes=tr1.inner_bytes - tr0.inner_bytes,
-            wall_seconds=time.perf_counter() - t0)
+            wall_seconds=time.perf_counter() - t0,
+            corrupt_blocks=tuple(corrupt))
         return state, report
 
     # -- repair ----------------------------------------------------------------

@@ -14,6 +14,12 @@ for real. Traffic accounting distinguishes inner- vs cross-cluster bytes —
 the quantity the paper's topology locality minimises — plus the
 aggregated tier: cross bytes that shipped as gateway-pre-folded blocks.
 
+A checksummed deployment keeps each block's CRC32C per chunk beside it
+(`set_checksums` / `checksums`; a `.crc` sidecar file on disk); writing a
+block without them drops the old ones, so none go stale. `corrupt` is the
+silent fault a checksum exists for: stored bytes change, their CRCs do
+not.
+
 The cluster/node model itself lives in `repro.topo.Topology` (this
 module's former private `ClusterTopology`, folded into the shared
 topology subsystem; the old name is kept as an alias).
@@ -26,6 +32,8 @@ import os
 import pathlib
 import threading
 from collections.abc import Callable, Iterator
+
+import numpy as np
 
 from repro import obs
 from repro.topo import Topology
@@ -132,6 +140,7 @@ class BlockStore:
         self.topo = topo
         self._blocks: dict[tuple, bytes] = {}       # (stripe, block) -> bytes
         self._block_node: dict[tuple, int] = {}
+        self._crcs: dict[tuple, bytes] = {}         # -> uint32 CRC per chunk
         self._failed: set[int] = set()
         self._latency: dict[int, float] = {}        # node -> simulated sec
         self.traffic = TrafficStats()
@@ -175,10 +184,53 @@ class BlockStore:
     def _put_nolisten(self, stripe: int, block: int, node: int, data):
         """Store one payload + index entry WITHOUT notifying listeners —
         the shared body of `put` (per-block notify) and `put_many` (one
-        batched notify). The only point where the in-memory and disk
+        batched notify). A block's old CRCs go with its old bytes."""
+        key = (stripe, block)
+        if key in self._crcs:
+            self._drop_crc(key, self._block_node[key])
+        self._write(key, node, data)
+        self._block_node[key] = node
+
+    def _write(self, key: tuple[int, int], node: int, data) -> None:
+        """Land one payload — the only point where the in-memory and disk
         tiers differ on the write path."""
-        self._blocks[(stripe, block)] = bytes(data)
-        self._block_node[(stripe, block)] = node
+        self._blocks[key] = bytes(data)
+
+    # -- checksums -----------------------------------------------------------
+    def set_checksums(self, items) -> None:
+        """Keep `(stripe, block), crcs` pairs beside their blocks: `crcs`
+        is the block's uint32 CRC32C per chunk (an array or its bytes)."""
+        for key, crcs in items:
+            self._put_crc(key, self._block_node[key], bytes(crcs))
+
+    def checksums(self, pairs) -> list[bytes | None]:
+        """The stored CRCs of each pair, None where a block has none."""
+        return [self._crcs.get(key) for key in pairs]
+
+    def _put_crc(self, key: tuple[int, int], node: int, crcs: bytes) -> None:
+        self._crcs[key] = crcs
+
+    def _drop_crc(self, key: tuple[int, int], node: int) -> None:
+        self._crcs.pop(key, None)
+
+    def corrupt(self, stripe: int, block: int, offset: int, nbytes: int,
+                pattern: int | bytes = 0xFF) -> None:
+        """Silent corruption, as a latent sector error or a bit flip on the
+        way to the platter: XOR `pattern` (one byte repeated, or `nbytes`
+        of them) into bytes [offset, offset + nbytes) of a stored block,
+        leaving its CRCs and its index entry as they were. No listener is
+        told: nothing wrote through the store."""
+        key = (stripe, block)
+        node = self._block_node[key]
+        mask = (np.full(nbytes, pattern, np.uint8) if isinstance(pattern, int)
+                else np.frombuffer(pattern, np.uint8))
+        data = np.frombuffer(self._payload(key, node), np.uint8).copy()
+        if (mask.size != nbytes or not mask.any() or offset < 0
+                or offset + nbytes > data.size):
+            raise ValueError(f"a non-zero pattern of {nbytes} bytes at "
+                             f"{offset} of a {data.size}-byte block")
+        data[offset:offset + nbytes] ^= mask
+        self._write(key, node, data.tobytes())
 
     def put(self, stripe: int, block: int, node: int, data: bytes):
         self._put_nolisten(stripe, block, node, data)
@@ -305,6 +357,8 @@ class BlockStore:
         """Simulate loss of a single block replica (latent sector error /
         scrub-detected corruption) while its node stays up. Lets tests and
         failure injection construct arbitrary per-stripe erasure patterns."""
+        if (stripe, block) in self._crcs:
+            self._drop_crc((stripe, block), self._block_node[(stripe, block)])
         self._blocks.pop((stripe, block), None)
         self._block_node.pop((stripe, block), None)
         self._notify_mutation(stripe, block)
@@ -312,13 +366,15 @@ class BlockStore:
     def delete_node_blocks(self, node: int):
         """Simulate permanent loss of a node's disks."""
         for key in self.blocks_on_node(node):
+            self._drop_crc(key, node)
             del self._blocks[key]
             del self._block_node[key]
             self._notify_mutation(*key)
 
 
 class DiskBlockStore(BlockStore):
-    """Durable tier: blocks live under root/node_<i>/s<stripe>_b<block>.
+    """Durable tier: blocks live under root/node_<i>/s<stripe>_b<block>,
+    their CRCs, if any, beside them in s<stripe>_b<block>.crc.
 
     Inherits the in-memory index for placement/failure bookkeeping but
     persists payloads to disk, so a process restart (the checkpoint/restart
@@ -335,12 +391,24 @@ class DiskBlockStore(BlockStore):
         d.mkdir(exist_ok=True)
         return d / f"s{stripe:06d}_b{block:04d}"
 
-    def _put_nolisten(self, stripe: int, block: int, node: int, data):
+    def _write(self, key: tuple[int, int], node: int, data) -> None:
         # put/put_many inherit from BlockStore and keep their listener
         # semantics; only the payload landing differs (file vs dict).
-        self._path(stripe, block, node).write_bytes(data)
-        self._blocks[(stripe, block)] = b""           # payload on disk
-        self._block_node[(stripe, block)] = node
+        self._path(*key, node).write_bytes(data)
+        self._blocks[key] = b""                       # payload on disk
+
+    def _crc_path(self, key: tuple[int, int], node: int) -> pathlib.Path:
+        path = self._path(*key, node)
+        return path.with_name(path.name + ".crc")
+
+    def _put_crc(self, key: tuple[int, int], node: int, crcs: bytes) -> None:
+        self._crc_path(key, node).write_bytes(crcs)
+        super()._put_crc(key, node, crcs)
+
+    def _drop_crc(self, key: tuple[int, int], node: int) -> None:
+        if key in self._crcs:
+            self._crc_path(key, node).unlink(missing_ok=True)
+        super()._drop_crc(key, node)
 
     def _payload(self, key: tuple[int, int], node: int) -> bytes:
         return self._path(key[0], key[1], node).read_bytes()
@@ -349,12 +417,18 @@ class DiskBlockStore(BlockStore):
         """Rebuild the index from the directory tree (restart path)."""
         self._blocks.clear()
         self._block_node.clear()
+        self._crcs.clear()
         for nd in sorted(self.root.glob("node_*")):
             node = int(nd.name.split("_")[1])
             for f in nd.iterdir():
-                s, b = f.name[1:].split("_b")
-                self._blocks[(int(s), int(b))] = b""
-                self._block_node[(int(s), int(b))] = node
+                name, crc = f.name.removesuffix(".crc"), f.suffix == ".crc"
+                s, b = name[1:].split("_b")
+                key = (int(s), int(b))
+                if crc:
+                    self._crcs[key] = f.read_bytes()
+                else:
+                    self._blocks[key] = b""
+                    self._block_node[key] = node
 
     def drop_block(self, stripe: int, block: int):
         node = self._block_node.get((stripe, block))
@@ -370,6 +444,7 @@ class DiskBlockStore(BlockStore):
             p = self._path(s, b, node)
             if p.exists():
                 p.unlink()
+            self._drop_crc(key, node)
             del self._blocks[key]
             del self._block_node[key]
             self._notify_mutation(s, b)

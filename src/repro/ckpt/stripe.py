@@ -43,11 +43,12 @@ import numpy as np
 
 from repro import obs
 from repro.core.codes import Code, make_unilrc
+from repro.core.crc32c import BYTES_PER_CHECKSUM
 from repro.core.metrics import locality_metrics
 from repro.core.mttdl import MTTDLParams, code_mttdl_years
 from repro.core.placement import Placement, default_placement
 from repro.io.backend import Backend, resolve_backend
-from repro.io.engine import CodingEngine, OpHandle
+from repro.io.engine import CodingEngine, FlushStats, OpHandle
 from repro.kernels import autotune, ops
 
 from repro.topo import Topology
@@ -133,7 +134,17 @@ class StripeCodec:
     changes. The default comes from `autotune.plan_stream_windows`: 64
     for small blocks, 3 for the widest paper code at 1 MiB blocks, where
     64 stripes would be a ~13 GiB codeword window and two of them would
-    not fit a v5e's 16 GiB of HBM."""
+    not fit a v5e's 16 GiB of HBM.
+
+    `checksum` is the deployment's block checksum: None (none kept), or
+    "crc32c", HDFS's default, one CRC32C per 512 bytes
+    (`crc32c.BYTES_PER_CHECKSUM`) of every block, kept beside it in the
+    store. With it, every block
+    written gets its CRCs and every block read is checked against them
+    before its bytes are used; a block that fails is rebuilt like a lost
+    one (see `repro.io.engine`)."""
+
+    CHECKSUMS = (None, "crc32c")
 
     def __init__(self, code: Code, store: BlockStore, *,
                  block_size: int = 1 << 20,
@@ -141,7 +152,14 @@ class StripeCodec:
                  backend: Backend | str | None = None,
                  use_kernels: bool | None = None,    # deprecated shim
                  max_batch_stripes: int | None = None,
-                 gateway_aggregation: bool = False):
+                 gateway_aggregation: bool = False,
+                 checksum: str | None = None):
+        if checksum not in self.CHECKSUMS:
+            raise ValueError(f"checksum {checksum!r} is not one of "
+                             f"{self.CHECKSUMS}")
+        if checksum and block_size % BYTES_PER_CHECKSUM:
+            raise ValueError(f"{block_size}-byte blocks are not whole "
+                             f"{BYTES_PER_CHECKSUM}-byte checksum chunks")
         self.code = code
         self.store = store
         self.block_size = block_size
@@ -151,9 +169,10 @@ class StripeCodec:
             max_batch_stripes = autotune.plan_stream_windows(
                 code.k, code.n, block_size)
         self.use_kernels = self.backend.uses_kernels
-        self.engine = CodingEngine(code, store, self.backend,
-                                   max_batch_stripes=max_batch_stripes,
-                                   gateway_aggregation=gateway_aggregation)
+        self.engine = CodingEngine(
+            code, store, self.backend, max_batch_stripes=max_batch_stripes,
+            gateway_aggregation=gateway_aggregation,
+            checksums=checksum is not None)
         self.max_batch_stripes = max_batch_stripes
         if self.placement.num_clusters > store.topo.num_clusters:
             raise ValueError(
@@ -200,7 +219,8 @@ class StripeCodec:
         twin.engine = CodingEngine(
             self.code, self.store, self.backend,
             max_batch_stripes=self.max_batch_stripes,
-            gateway_aggregation=self.engine.gateway_aggregation)
+            gateway_aggregation=self.engine.gateway_aggregation,
+            checksums=self.engine.checksums)
         twin._block_slot = self._block_slot
         twin._stripes = self._stripes
         return twin
@@ -265,6 +285,13 @@ class StripeCodec:
                     self.store.put(sid, b, self._node_for(sid, b),
                                    codewords[i, b].tobytes())
                 self._record_meta(sid, batch_start + i, len(buf), metas)
+            if self.engine.checksums:
+                crcs = self.engine.checksums_of(
+                    codewords.reshape(-1, codewords.shape[-1]))
+                self.store.set_checksums(zip(
+                    ((start_stripe + batch_start + i, b)
+                     for i in range(batch_n) for b in range(self.code.n)),
+                    crcs))
         return metas
 
     def write_stream(self, buf, *, start_stripe: int = 0,
@@ -317,7 +344,7 @@ class StripeCodec:
             for w0 in starts:
                 yield view(w0, min(window, nstripes - w0))
 
-        def land(idx: int, codewords: np.ndarray) -> None:
+        def land(idx: int, codewords: np.ndarray, crcs) -> None:
             w0 = starts[idx]
             entries = []
             for i in range(codewords.shape[0]):
@@ -326,6 +353,10 @@ class StripeCodec:
                     entries.append((sid, b, self._node_for(sid, b),
                                     codewords[i, b]))
             self.store.put_many(entries)
+            if crcs is not None:
+                self.store.set_checksums(
+                    ((sid, b), crcs[sid - start_stripe - w0, b])
+                    for sid, b, _node, _data in entries)
             for i in range(codewords.shape[0]):
                 self._record_meta(start_stripe + w0 + i, w0 + i,
                                   total, metas)
@@ -392,11 +423,18 @@ class StripeCodec:
         """Read the k data blocks; unavailable ones are recovered in the
         same engine flush — one launch per erasure pattern / fast group,
         not one decode per missing block."""
+        return self.read_stripe(meta, reader_cluster=reader_cluster)[0]
+
+    def read_stripe(self, meta: StripeMeta, *,
+                    reader_cluster: int | None = None
+                    ) -> tuple[bytes, FlushStats]:
+        """`normal_read`, with its flush's stats: among them the blocks
+        found corrupt, each rebuilt like an unavailable one."""
         with obs.span("stripe.read", stripe=meta.stripe_id):
             finish = self.plan_normal_read(meta,
                                            reader_cluster=reader_cluster)
-            self.engine.flush()
-            return finish()
+            stats = self.engine.flush()
+            return finish(), stats
 
     def degraded_read(self, meta: StripeMeta, block: int, *,
                       reader_cluster: int | None = None) -> bytes:
@@ -546,19 +584,22 @@ class StripeCodec:
         def finish() -> tuple[int, RecoveryStats]:
             occupied = self.store.nodes_holding_many(
                 {sid for sid, _b in pairs})
-            placed = 0
-            for (sid, b) in pairs:
-                data = handles[(sid, b)].result()
-                if data is None:             # unrecoverable right now
-                    continue
+            rebuilt = {p: handles[p].result() for p in pairs}
+            rebuilt = {p: d for p, d in rebuilt.items()
+                       if d is not None}     # None: unrecoverable right now
+            placed = []
+            for (sid, b), data in rebuilt.items():
                 occ = occupied[sid]
                 cand = self._pick_rebuild_node(sid, b, occ, exclude_node)
                 if cand is None:
                     continue
                 self.store.put(sid, b, cand, data)
                 occ.add(cand)
-                placed += 1
-            return placed, _stats_from_handles(handles)
+                placed.append((sid, b))
+            if placed and self.engine.checksums:
+                self.store.set_checksums(zip(placed, self.engine.checksums_of(
+                    [rebuilt[p] for p in placed])))
+            return len(placed), _stats_from_handles(handles)
         return finish
 
     def rebuild_blocks(self, pairs: list[tuple[int, int]], *,

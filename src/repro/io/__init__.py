@@ -7,7 +7,7 @@ topo → core → kernels → io → ckpt → launch."""
 from .backend import (BACKENDS, Backend, KernelBackend, NumpyBackend,
                       resolve_backend)
 from .cache import CacheStats, HotBlockCache
-from .engine import CodingEngine, FlushStats, OpHandle
+from .engine import CodingEngine, CorruptionError, FlushStats, OpHandle
 # Priority/ClassStats canonically live in repro.priority; re-exported
 # here because the io layer is where most consumers meet them.
 from .frontend import (ClassStats, MergedHandle, Priority, RequestFrontend,
@@ -19,7 +19,7 @@ from .workload import (Arrival, CompletedRequest, ServiceModel,
 __all__ = ["BACKENDS", "Backend", "KernelBackend", "NumpyBackend",
            "resolve_backend",
            "CacheStats", "HotBlockCache",
-           "CodingEngine", "FlushStats", "OpHandle",
+           "CodingEngine", "CorruptionError", "FlushStats", "OpHandle",
            "ClassStats", "MergedHandle", "Priority", "RequestFrontend",
            "RequestHandle", "RequestShed", "ScrubReport", "ServiceSample",
            "ShardedFrontend",

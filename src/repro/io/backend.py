@@ -14,9 +14,9 @@ The `CodingEngine` (engine.py) is backend-agnostic: it groups op
 descriptors into batches and hands each batch to exactly one backend
 call, so "which device executes the bytes" is a constructor argument,
 not a branch on every code path. Inputs and outputs are host numpy
-uint8 arrays, and the kernel backend owns the device round-trip; the one
-exception is `encode_many_lazy`, which also takes stripes already on the
-device and encodes them there.
+uint8 arrays, and the kernel backend owns the device round-trip; the
+exceptions are `encode_many_lazy` and `checksum_many`, which also take
+arrays already on the device and leave their results there.
 """
 from __future__ import annotations
 
@@ -28,6 +28,7 @@ import numpy as np
 from repro import obs
 from repro.core.codec import DecodePlan, RecoveryPlan
 from repro.core.codes import Code
+from repro.core.crc32c import crc32c_chunks
 from repro.core.gf import gf_matmul
 
 
@@ -77,6 +78,20 @@ class Backend(abc.ABC):
         pre-fold primitive (each remote cluster folds its XOR-linear
         contribution before it ships) and the final combine of folded
         partials at the reader."""
+
+    @abc.abstractmethod
+    def checksum_many(self, blocks, nbytes: int):
+        """(..., B) uint8 -> (..., B // nbytes) uint32: the CRC32C of every
+        `nbytes`-byte chunk (`core.crc32c`). Lazy as `encode_many_lazy`:
+        the caller forces the result, so the kernel backend can compute
+        the CRCs of codewords still on the device and bring them home
+        with them."""
+
+    @abc.abstractmethod
+    def verify_many(self, blocks: np.ndarray, expect: np.ndarray,
+                    nbytes: int) -> np.ndarray:
+        """(N, B) uint8 blocks and their stored (N, B // nbytes) uint32
+        CRCs -> (N,) int32: how many chunks of each block fail."""
 
 
 class KernelBackend(Backend):
@@ -137,6 +152,22 @@ class KernelBackend(Backend):
                       from_device=S * B):
             return obs.to_host(ops.xor_fold_many(obs.to_device(stacked)))
 
+    def checksum_many(self, blocks, nbytes):
+        from repro.kernels import ops
+        copied = 0 if isinstance(blocks, jax.Array) else blocks.nbytes
+        with obs.span("backend.checksum_many", to_device=copied,
+                      from_device=0):
+            # un-forced jax array
+            return ops.checksum_many(obs.to_device(blocks), nbytes)
+
+    def verify_many(self, blocks, expect, nbytes):
+        from repro.kernels import ops
+        with obs.span("backend.verify_many",
+                      to_device=blocks.nbytes + expect.nbytes,
+                      from_device=4 * len(blocks)):
+            return obs.to_host(ops.verify_many(
+                obs.to_device(blocks), obs.to_device(expect), nbytes))
+
 
 def _on_device(stacked: dict[int, np.ndarray], sources) -> dict:
     """{source: (S, B)} of the plan's sources, copied to the device."""
@@ -170,6 +201,13 @@ class NumpyBackend(Backend):
         for i in range(stacked.shape[1]):
             out ^= stacked[:, i]
         return out
+
+    def checksum_many(self, blocks, nbytes):
+        return crc32c_chunks(blocks, nbytes)
+
+    def verify_many(self, blocks, expect, nbytes):
+        bad = crc32c_chunks(blocks, nbytes) != expect
+        return bad.sum(axis=-1, dtype=np.int32)
 
 
 #: Registry behind the string spelling of `backend=`. Constructors are

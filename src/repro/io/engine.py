@@ -42,6 +42,21 @@ The engine is deliberately ignorant of placement and stripe metadata:
 it executes byte math and store I/O. Deciding *which* ops realize a
 request (read vs recover, which blocks, where rebuilt blocks land) is
 the planner's job in `ckpt/stripe.py`.
+
+Checksums (`checksums=True`; CRC32C per `BYTES_PER_CHECKSUM` bytes):
+every block the engine writes gets its CRC32C per chunk beside it,
+computed by the backend (`Backend.checksum_many`; for a streamed encode,
+on the device beside the codewords). Every flush reads through one
+reader: `_Reads`, the store's own reads, or with checksums `_Verified`,
+which checks every block against its stored CRCs once, on the backend,
+before any op uses its bytes. A read whose block fails becomes a
+recover, and a
+block that fails is an erasure of its stripe for the rest of the flush —
+recover groups whose sources fail are planned again with it erased, so a
+lone corrupt block still takes its group's XOR fold and more take the
+pattern decode. More than the code tolerates fails the ops with
+`CorruptionError`, never with bytes. The blocks found corrupt are listed
+on `FlushStats.corrupt`; the store keeps them as they are.
 """
 from __future__ import annotations
 
@@ -54,6 +69,7 @@ import numpy as np
 from repro import obs
 from repro.core.codec import decode_plan_cached, plans_for
 from repro.core.codes import Code
+from repro.core.crc32c import BYTES_PER_CHECKSUM
 from repro.kernels import ops as kernel_ops
 from repro.topo import plan_is_xor_linear
 
@@ -106,6 +122,14 @@ class _Op:
     new_data: bytes | None = None         # update payload
 
 
+class CorruptionError(Exception):
+    """Blocks failed their checksums beyond what the code can rebuild."""
+
+
+class _SourcesCorrupt(Exception):
+    """A recover group's sources failed their checksums: plan it again."""
+
+
 @dataclasses.dataclass
 class FlushStats:
     """How one flush carved the pending ops into batched backend calls."""
@@ -119,6 +143,8 @@ class FlushStats:
     inner_bytes: int = 0       # store bytes this flush moved, inner tier
     cross_bytes: int = 0       # ... across cluster gateways
     aggregated_bytes: int = 0  # of cross_bytes: gateway pre-folded
+    corrupt: list = dataclasses.field(default_factory=list)
+    #                          (stripe, block) pairs that failed their CRCs
 
     @property
     def plan_groups(self) -> int:
@@ -134,9 +160,11 @@ class CodingEngine:
 
     def __init__(self, code: Code, store: Any, backend: Backend, *,
                  max_batch_stripes: int = 64,
-                 gateway_aggregation: bool = False):
+                 gateway_aggregation: bool = False,
+                 checksums: bool = False):
         if max_batch_stripes < 1:
             raise ValueError("max_batch_stripes must be >= 1")
+        self.checksums = checksums
         self.code = code
         self.store = store
         self.backend = backend
@@ -207,6 +235,7 @@ class CodingEngine:
             analyze_flush(self, raise_on_violation=True)
         ops_list, self._pending = self._pending, []
         stats = FlushStats(ops=len(ops_list))
+        reads = _Verified(self) if self.checksums else _Reads(self)
         by_kind: dict[str, list[_Op]] = {}
         for op in ops_list:
             by_kind.setdefault(op.kind, []).append(op)
@@ -218,47 +247,80 @@ class CodingEngine:
                 kernel_ops.launch_scope() as scope, \
                 self.store.traffic.scoped() as tdelta:
             self._run_encodes(by_kind.get("encode", []), stats)
-            self._run_reads(by_kind.get("read", []))
-            self._run_recovers(by_kind.get("recover", []), stats)
-            self._run_updates(by_kind.get("update", []), stats)
+            corrupt = self._run_reads(by_kind.get("read", []), reads)
+            self._run_recovers(by_kind.get("recover", []) + corrupt, stats,
+                               reads)
+            self._run_updates(by_kind.get("update", []), stats, reads)
+        stats.corrupt = sorted(reads.bad)
         stats.launches = scope.total
         stats.inner_bytes = tdelta.inner_bytes
         stats.cross_bytes = tdelta.cross_bytes
         stats.aggregated_bytes = tdelta.aggregated_bytes
         return stats
 
+    # -- checksums -----------------------------------------------------------
+    def checksums_of(self, blocks) -> np.ndarray:
+        """(N, chunks) uint32: the CRCs of N blocks to be written, each
+        bytes or a (B,) array, in one backend call."""
+        return obs.to_host(self._checksum(np.stack(
+            [np.frombuffer(b, np.uint8) for b in blocks])))
+
+    def _checksum(self, blocks):
+        """Dispatch the CRCs of (..., B) blocks; the result is lazy."""
+        n = int(np.prod(blocks.shape[:-1]))
+        chunks = n * (blocks.shape[-1] // BYTES_PER_CHECKSUM)
+        with obs.span("engine.checksum", blocks=n, chunks=chunks):
+            out = self.backend.checksum_many(blocks, BYTES_PER_CHECKSUM)
+        obs.count_checksums(chunks_made=chunks)
+        return out
+
     # -- reads ---------------------------------------------------------------
-    def _run_reads(self, ops_list: list[_Op]) -> None:
+    def _run_reads(self, ops_list: list[_Op], reads: "_Reads") -> list[_Op]:
+        """Resolve the read ops; return those whose block failed its CRCs,
+        turned into recovers."""
         by_rc: dict[int | None, list[_Op]] = {}
         for op in ops_list:
             by_rc.setdefault(op.reader_cluster, []).append(op)
+        corrupt: list[_Op] = []
         for rc, group in sorted(by_rc.items(),
                                 key=lambda kv: (kv[0] is None, kv[0] or 0)):
             pairs = list(dict.fromkeys((op.stripe, op.block)
                                        for op in group))
             try:
-                got = self.store.get_many(pairs, reader_cluster=rc)
+                got = reads.get_many(pairs, reader_cluster=rc)
             except Exception:
                 # A bad pair fails the whole batched check before any
                 # traffic is recorded; retry per op so only the ops that
                 # actually touch the failed/missing block error out.
+                got = {}
                 for op in group:
                     try:
-                        op.handle._set(self.store.get(
-                            op.stripe, op.block, reader_cluster=rc))
+                        got.update(reads.get_many([(op.stripe, op.block)],
+                                                  reader_cluster=rc))
                     except Exception as exc:
                         op.handle._fail(exc)
-                continue
             for op in group:
-                op.handle._set(got[(op.stripe, op.block)])
+                if op.handle.done:
+                    continue
+                if (op.stripe, op.block) in got:
+                    op.handle._set(got[(op.stripe, op.block)])
+                else:
+                    op.kind = "recover"
+                    corrupt.append(op)
+        return corrupt
 
     # -- recovers (the pattern-grouped engine) -------------------------------
-    def _gather_sources(self, sids: list[int], sources: tuple[int, ...],
-                        rc: int | None) -> dict[int, np.ndarray]:
+    @staticmethod
+    def _gather_sources(sids: list[int], sources: tuple[int, ...],
+                        rc: int | None, reads: "_Reads"
+                        ) -> dict[int, np.ndarray]:
         """{source block id: (S, B)} for a plan group, read via ONE
-        get_many batch."""
-        got = self.store.get_many(
-            [(sid, s) for sid in sids for s in sources], reader_cluster=rc)
+        get_many batch; raises `_SourcesCorrupt` when one fails its
+        CRCs."""
+        pairs = [(sid, s) for sid in sids for s in sources]
+        got = reads.get_many(pairs, reader_cluster=rc)
+        if len(got) < len(pairs):
+            raise _SourcesCorrupt
         return {s: np.stack([np.frombuffer(got[(sid, s)], np.uint8)
                              for sid in sids]) for s in sources}
 
@@ -275,7 +337,7 @@ class CodingEngine:
                      for s in sources)
 
     def _recover_xor_batch(self, sids: list[int], sources: tuple[int, ...],
-                           rc: int | None) -> np.ndarray:
+                           rc: int | None, reads: "_Reads") -> np.ndarray:
         """Gateway-aggregated execution of one XOR-linear plan over a
         stripe batch: remote clusters holding >= 2 sources read them
         locally (inner-tier bytes at THEIR gateway), fold them with one
@@ -300,10 +362,10 @@ class CodingEngine:
                      if c != rc and len(ss) > 1}
             parts: list[np.ndarray] = []
             if direct:
-                got = self._gather_sources(gsids, tuple(direct), rc)
+                got = self._gather_sources(gsids, tuple(direct), rc, reads)
                 parts += [got[s] for s in direct]
             for c, ss in sorted(folds.items()):
-                got = self._gather_sources(gsids, tuple(ss), c)
+                got = self._gather_sources(gsids, tuple(ss), c, reads)
                 partial = self.backend.xor_fold_many(
                     np.stack([got[s] for s in ss], axis=1))
                 self.store.traffic.add_shipped(int(partial.nbytes))
@@ -313,16 +375,22 @@ class CodingEngine:
                 results[i] = row
         return np.stack(results)
 
-    def _run_recovers(self, ops_list: list[_Op], stats: FlushStats) -> None:
+    def _run_recovers(self, ops_list: list[_Op], stats: FlushStats,
+                      reads: "_Reads") -> None:
         by_rc: dict[int | None, list[_Op]] = {}
         for op in ops_list:
             by_rc.setdefault(op.reader_cluster, []).append(op)
         for rc, group in sorted(by_rc.items(),
                                 key=lambda kv: (kv[0] is None, kv[0] or 0)):
-            self._recover_cluster_group(rc, group, stats)
+            while group:     # again for ops whose sources failed their CRCs
+                group = self._recover_cluster_group(rc, group, stats, reads)
 
     def _recover_cluster_group(self, rc: int | None, group: list[_Op],
-                               stats: FlushStats) -> None:
+                               stats: FlushStats, reads: "_Reads"
+                               ) -> list[_Op]:
+        """Recover the group's ops; return those to plan again, whose
+        sources turned out corrupt (each time one more block is known
+        bad, so the retries end)."""
         pair_ops: dict[tuple[int, int], list[_Op]] = {}
         by_stripe: dict[int, list[int]] = {}
         for op in group:
@@ -332,12 +400,14 @@ class CodingEngine:
             pair_ops.setdefault(key, []).append(op)
         plans = plans_for(self.code)
         n = self.code.n
+        bad = reads.bad
+        retry: list[_Op] = []
         fast: dict[int, list[int]] = {}      # block id -> [stripe ids]
         # pattern -> [(stripe id, requested blocks under that pattern)]
         slow: dict[tuple[int, ...], list[tuple[int, list[int]]]] = {}
         for sid in sorted(by_stripe):
             eset = {b for b in range(n)
-                    if not self.store.available(sid, b)}
+                    if not self.store.available(sid, b) or (sid, b) in bad}
             slow_blocks = []
             for b in by_stripe[sid]:
                 if eset.intersection(plans[b].sources):
@@ -372,11 +442,14 @@ class CodingEngine:
                                   sources=len(plan.sources)):
                         if aggregate:
                             rec = self._recover_xor_batch(
-                                batch, plan.sources, rc)
+                                batch, plan.sources, rc, reads)
                         else:
                             stacked = self._gather_sources(
-                                batch, plan.sources, rc)
+                                batch, plan.sources, rc, reads)
                             rec = self.backend.recover_many(plan, stacked)
+                except _SourcesCorrupt:
+                    retry += [op for sid in batch for op in pair_ops[(sid, b)]]
+                    continue
                 except Exception as exc:
                     fail_pairs([(sid, b) for sid in batch], exc)
                     continue
@@ -388,6 +461,13 @@ class CodingEngine:
             try:
                 dplan = decode_plan_cached(self.code, pattern)
             except ValueError as exc:   # beyond the code's tolerance now
+                lost = [(sid, b) for sid, _ in entries for b in pattern
+                        if (sid, b) in bad]
+                if lost:
+                    exc = CorruptionError(
+                        f"blocks {lost} failed their checksums; with the "
+                        f"rest of pattern {pattern} that is beyond "
+                        f"{self.code.name}'s tolerance")
                 for key in keys:
                     for op in pair_ops[key]:
                         if op.strict:
@@ -408,8 +488,12 @@ class CodingEngine:
                     with obs.span("engine.recover", stripes=len(sids),
                                   sources=len(dplan.sources)):
                         stacked = self._gather_sources(sids, dplan.sources,
-                                                       rc)
+                                                       rc, reads)
                         rec = self.backend.apply_decode_many(dplan, stacked)
+                except _SourcesCorrupt:
+                    retry += [op for sid, blocks in chunk for b in blocks
+                              for op in pair_ops[(sid, b)]]
+                    continue
                 except Exception as exc:
                     fail_pairs([(sid, b) for sid, blocks in chunk
                                 for b in blocks], exc)
@@ -419,6 +503,7 @@ class CodingEngine:
                         resolve(sid, b, rec[b][i].tobytes(), "pattern",
                                 ("pattern", pattern))
                         stats.multi_pairs += 1
+        return retry
 
     # -- encodes -------------------------------------------------------------
     def _run_encodes(self, ops_list: list[_Op], stats: FlushStats) -> None:
@@ -467,8 +552,10 @@ class CodingEngine:
         nothing is copied here), each with S_w <= max_batch_stripes:
         host arrays, or `jax.Array`s already on the device, which stay
         there (the backend encodes them in place);
-        `sink(index, codewords)` receives every window's forced (S_w,
-        n, B) result, in order. The pipeline overlap: window w+1's
+        `sink(index, codewords, crcs)` receives every window's forced
+        (S_w, n, B) result, in order, with its (S_w, n, chunks) CRCs
+        (None without checksums), computed where the codewords are and
+        brought home with them. The pipeline overlap: window w+1's
         encode is DISPATCHED (`Backend.encode_many_lazy` — un-forced
         jax array on the kernel backend) before window w's result is
         forced and handed to the sink, so device compute runs while the
@@ -499,25 +586,28 @@ class CodingEngine:
                             f"window of {data.shape[0]} stripes outside [1, "
                             f"max_batch_stripes={self.max_batch_stripes}]")
                     fut = self.backend.encode_many_lazy(self.code, data)
+                crc = self._checksum(fut) if self.checksums else None
                 if prev is not None:
                     self._land(sink, *prev)
-                prev = (served, fut)
+                prev = (served, fut, crc)
                 served += 1
             if prev is not None:
                 self._land(sink, *prev)
         return served
 
     @staticmethod
-    def _land(sink, index: int, fut) -> None:
-        """Force one window's codewords to the host (`encode_wait`), then
-        hand them to the sink (`land`)."""
+    def _land(sink, index: int, fut, crc) -> None:
+        """Force one window's codewords and CRCs to the host
+        (`encode_wait`), then hand them to the sink (`land`)."""
         with obs.span("engine.encode_wait", stripes=len(fut)):
             codewords = obs.to_host(fut)
+            crcs = None if crc is None else obs.to_host(crc)
         with obs.span("engine.land", stripes=len(codewords)):
-            sink(index, codewords)
+            sink(index, codewords, crcs)
 
     # -- delta updates -------------------------------------------------------
-    def _run_updates(self, ops_list: list[_Op], stats: FlushStats) -> None:
+    def _run_updates(self, ops_list: list[_Op], stats: FlushStats,
+                     reads: "_Reads") -> None:
         # Waves: submission order, one op per stripe per wave (updates of
         # one stripe share parity blocks, so they must see each other's
         # writes), uniform payload length + reader cluster per wave so
@@ -538,9 +628,10 @@ class CodingEngine:
                 stripes.add(op.stripe)
                 wave.append(op)
             remaining = deferred
-            self._run_update_wave(wave, stats)
+            self._run_update_wave(wave, stats, reads)
 
-    def _run_update_wave(self, wave: list[_Op], stats: FlushStats) -> None:
+    def _run_update_wave(self, wave: list[_Op], stats: FlushStats,
+                         reads: "_Reads") -> None:
         code, k = self.code, self.code.k
         rc = wave[0].reader_cluster
         touched_of = {}
@@ -552,9 +643,14 @@ class CodingEngine:
             read_pairs += [(op.stripe, k + pi) for pi in touched_of[id(op)]]
         # Stage phase: EVERY read happens before ANY write, one batched
         # get_many — a NodeFailure anywhere aborts the whole wave with
-        # every stripe untouched.
+        # every stripe untouched. A corrupt block aborts it too: a delta
+        # on its bytes would hide the corruption under fresh CRCs.
         try:
-            got = self.store.get_many(read_pairs, reader_cluster=rc)
+            got = reads.get_many(read_pairs, reader_cluster=rc)
+            if len(got) < len(dict.fromkeys(read_pairs)):
+                raise CorruptionError(
+                    f"update reads blocks that failed their checksums: "
+                    f"{sorted(reads.bad)}")
         except Exception as exc:
             for op in wave:
                 op.handle._fail(exc)
@@ -578,6 +674,19 @@ class CodingEngine:
                 for r, (u, pi) in enumerate(rows):
                     M[r, u] = code.A[pi, wave[u].block]
                 terms = self.backend.delta_terms(M, np.stack(deltas))
+            writes: dict[int, list[tuple[int, bytes]]] = {}
+            r = 0
+            for op in wave:
+                writes[id(op)] = [(op.block, op.new_data)]
+                for pi in touched_of[id(op)]:
+                    pold = np.frombuffer(got[(op.stripe, k + pi)], np.uint8)
+                    writes[id(op)].append((k + pi,
+                                           (pold ^ terms[r]).tobytes()))
+                    r += 1
+            crcs = None
+            if self.checksums:
+                crcs = iter(self.checksums_of(
+                    [data for ws in writes.values() for _, data in ws]))
         except Exception as exc:       # nothing written yet: wave aborts
             for op in wave:
                 op.handle._fail(exc)
@@ -586,23 +695,97 @@ class CodingEngine:
         # Apply phase: every source value is staged, so no read can fail
         # between the first and last put. A put() error is a genuine
         # partial write — surface it on every unresolved handle rather
-        # than stranding them pending forever.
+        # than stranding them pending forever. A later wave of this flush
+        # touches the same parities: it reads what this one wrote.
+        reads.forget(read_pairs)
         try:
-            r = 0
-            for u, op in enumerate(wave):
+            for op in wave:
                 sid = op.stripe
-                self.store.put(sid, op.block,
-                               self.store.node_of(sid, op.block),
-                               op.new_data)
-                for pi in touched_of[id(op)]:
-                    pblock = k + pi
-                    pold = np.frombuffer(got[(sid, pblock)], np.uint8)
-                    self.store.put(sid, pblock,
-                                   self.store.node_of(sid, pblock),
-                                   (pold ^ terms[r]).tobytes())
-                    r += 1
+                for block, data in writes[id(op)]:
+                    self.store.put(sid, block, self.store.node_of(sid, block),
+                                   data)
+                if crcs is not None:
+                    self.store.set_checksums(
+                        ((sid, block), next(crcs))
+                        for block, _ in writes[id(op)])
                 op.handle._set(len(touched_of[id(op)]))
         except Exception as exc:
             for op in wave:
                 if not op.handle.done:
                     op.handle._fail(exc)
+
+
+class _Reads:
+    """One flush's block reads, in place of `store.get_many`: without
+    checksums, the store's own."""
+
+    bad: frozenset = frozenset()
+
+    def __init__(self, engine: CodingEngine):
+        self.store = engine.store
+
+    def get_many(self, pairs, *, reader_cluster: int | None = None
+                 ) -> dict[tuple[int, int], bytes]:
+        return self.store.get_many(pairs, reader_cluster=reader_cluster)
+
+    def forget(self, pairs) -> None:
+        """`pairs` are about to be written: read them from the store."""
+
+
+class _Verified(_Reads):
+    """The reads of a checksummed store: each block is checked against
+    its stored CRCs once, on the backend, before any op sees its bytes,
+    and kept until the flush writes it, so a fold's sources read earlier
+    as data are neither read nor checked again. A block that fails joins
+    `bad`."""
+
+    def __init__(self, engine: CodingEngine):
+        super().__init__(engine)
+        self.backend = engine.backend
+        self.good: dict[tuple[int, int], bytes] = {}
+        self.bad: set[tuple[int, int]] = set()
+
+    def get_many(self, pairs, *, reader_cluster: int | None = None
+                 ) -> dict[tuple[int, int], bytes]:
+        """The bytes of those of `pairs` that pass their CRCs."""
+        pairs = list(dict.fromkeys(pairs))
+        new = [p for p in pairs if p not in self.good and p not in self.bad]
+        if new:
+            got = self.store.get_many(new, reader_cluster=reader_cluster)
+            for p, failing in zip(new, self._verify(new, got)):
+                if failing:
+                    self.bad.add(p)
+                else:
+                    self.good[p] = got[p]
+        return {p: self.good[p] for p in pairs if p in self.good}
+
+    def forget(self, pairs) -> None:
+        for p in pairs:
+            self.good.pop(p, None)
+
+    def _verify(self, pairs: list[tuple[int, int]],
+                got: dict[tuple[int, int], bytes]) -> list[int]:
+        """How many chunks of each pair's bytes fail their stored CRCs, in
+        one backend call; a block with no CRCs fails in every chunk."""
+        stored = self.store.checksums(pairs)
+        have = [i for i, crcs in enumerate(stored) if crcs is not None]
+        B = len(got[pairs[0]])
+        chunks = B // BYTES_PER_CHECKSUM
+        failing = [chunks] * len(pairs)
+        with obs.span("engine.verify", blocks=len(pairs),
+                      chunks=len(pairs) * chunks) as span:
+            if have:
+                blocks = np.stack([np.frombuffer(got[pairs[i]], np.uint8)
+                                   for i in have])
+                expect = np.stack([np.frombuffer(stored[i], np.uint32)
+                                   for i in have])
+                counts = self.backend.verify_many(blocks, expect,
+                                                  BYTES_PER_CHECKSUM)
+                for i, n in zip(have, counts):
+                    failing[i] = int(n)
+            corrupt = sum(n > 0 for n in failing)
+            span.set_metadata(corrupt=corrupt)
+        obs.count_checksums(bytes_verified=len(have) * B,
+                            corrupt_chunks=sum(failing),
+                            corrupt_blocks=corrupt)
+        return failing

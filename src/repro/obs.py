@@ -17,6 +17,14 @@ SAVE_PATHS counts checkpoint saves by the path their bytes took:
 `device` (flattened and encoded on the device, only codewords come home)
 or `host` (flattened to host bytes first), for a job to read in-process
 with no profiler running. `count_save` is its mutation point.
+
+CHECKSUMS counts the work of a checksummed store (`CheckpointManager`'s
+`checksum="crc32c"`): `chunks_made` (CRCs computed for blocks written),
+`bytes_verified` (block bytes checked against their stored CRCs on a
+read), `corrupt_chunks` and `corrupt_blocks` (what failed). A span
+`repro.engine.verify` wraps each check, and `repro.engine.checksum` each
+dispatch of a save's CRCs. `count_checksums` is its mutation point;
+`checksum_snapshot()` copies the totals, so a caller takes a delta.
 """
 from __future__ import annotations
 
@@ -28,6 +36,9 @@ import numpy as np
 
 TRANSFERS: collections.Counter = collections.Counter()
 SAVE_PATHS: collections.Counter = collections.Counter()
+CHECKSUMS: collections.Counter = collections.Counter()
+CHECKSUM_KEYS = ("chunks_made", "bytes_verified", "corrupt_chunks",
+                 "corrupt_blocks")
 _LOCK = threading.Lock()
 
 
@@ -48,6 +59,17 @@ def count_save(path: str) -> None:
 def snapshot() -> dict[str, int]:
     with _LOCK:
         return {d: TRANSFERS[d] for d in ("to_device", "from_device")}
+
+
+def count_checksums(**counts: int) -> None:
+    with _LOCK:
+        for key, n in counts.items():
+            CHECKSUMS[key] += int(n)
+
+
+def checksum_snapshot() -> dict[str, int]:
+    with _LOCK:
+        return {key: CHECKSUMS[key] for key in CHECKSUM_KEYS}
 
 
 def to_device(x) -> jax.Array:

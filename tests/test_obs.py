@@ -1,7 +1,8 @@
 """Program spans and host<->device byte counters (`repro.obs`): a tiny
 checkpoint save and degraded restore, profiled on the CPU, leave every
 span in the trace, and the counters read exactly the bytes worked out
-from the shapes."""
+from the shapes; so do a checksummed save and a restore that finds a
+corrupt block."""
 import glob
 import sys
 import threading
@@ -151,3 +152,79 @@ def test_counters_lose_no_update_across_threads():
     finally:
         sys.setswitchinterval(interval)
     assert obs.snapshot()["to_device"] - before == 16 * 200 * 64
+
+
+@pytest.fixture(scope="module")
+def checked(tmp_path_factory):
+    """The same save by a manager that keeps CRC32C per 512 bytes, by the
+    device path and by the host path, then a restore with one data block
+    corrupt, inside one trace; the checksum counters' deltas over each."""
+    code = make_unilrc(1, 6)
+    mgrs = {"device": CheckpointManager(
+        BlockStore(Topology(6, 8)), code, block_size=BLOCK,
+        checksum="crc32c", host_platforms=frozenset()),
+        "host": CheckpointManager(BlockStore(Topology(6, 8)), code,
+                                  block_size=BLOCK, checksum="crc32c")}
+    state = _state()
+    jax.block_until_ready(state)
+    mgr = mgrs["device"]
+    log_dir = str(tmp_path_factory.mktemp("trace"))
+    with jax.profiler.trace(log_dir):
+        c0 = obs.checksum_snapshot()
+        mgr.save(state, step=3)
+        c1 = obs.checksum_snapshot()
+        mgrs["host"].save(state, step=3)
+        c2 = obs.checksum_snapshot()
+        sid = mgr.stripes_of(3)[0].stripe_id
+        mgr.store.corrupt(sid, 2, 0, BLOCK, 0x81)
+        back, report = mgr.restore(3)
+        c3 = obs.checksum_snapshot()
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    deltas = [{d: b[d] - a[d] for d in a}
+              for a, b in ((c0, c1), (c1, c2), (c2, c3))]
+    return dict(data=ProfileData.from_file(path), deltas=deltas, mgr=mgr,
+                code=code, state=state, back=back, report=report, sid=sid)
+
+
+def _events(data, name):
+    return [dict(ev.stats) for plane in data.planes for line in plane.lines
+            for ev in line.events if ev.name == name]
+
+
+@pytest.mark.parametrize("path", ["device", "host"])
+def test_a_save_makes_a_crc_per_chunk(checked, path):
+    """Every block of every stripe gets its CRCs, on either path."""
+    code = checked["code"]
+    stripes = len(checked["mgr"].stripes_of(3))
+    delta = checked["deltas"][{"device": 0, "host": 1}[path]]
+    assert delta == {"chunks_made": stripes * code.n * BLOCK // 512,
+                     "bytes_verified": 0, "corrupt_chunks": 0,
+                     "corrupt_blocks": 0}
+
+
+def test_a_restore_checks_each_block_it_reads_once(checked):
+    """The data blocks of every stripe, and the corrupt block's group's
+    two parities, which the fold reads besides its data: each checked
+    once; the corrupt block's every chunk fails."""
+    code, mgr = checked["code"], checked["mgr"]
+    stripes = len(mgr.stripes_of(3))
+    assert checked["report"].corrupt_blocks == ((checked["sid"], 2),)
+    for a, b in zip(jax.tree_util.tree_leaves(checked["state"]),
+                    jax.tree_util.tree_leaves(checked["back"])):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert checked["deltas"][2] == {
+        "chunks_made": 0,
+        "bytes_verified": (stripes * code.k + 2) * BLOCK,
+        "corrupt_chunks": BLOCK // 512, "corrupt_blocks": 1}
+
+
+def test_the_checksum_spans_carry_their_counts(checked):
+    code = checked["code"]
+    stripes = len(checked["mgr"].stripes_of(3))
+    made = _events(checked["data"], "repro.engine.checksum")
+    assert sum(s["blocks"] for s in made) == 2 * stripes * code.n
+    assert sum(s["chunks"] for s in made) == 2 * stripes * code.n * 128
+    verify = _events(checked["data"], "repro.engine.verify")
+    assert sum(s["blocks"] for s in verify) == stripes * code.k + 2
+    assert sum(s["chunks"] for s in verify) == (stripes * code.k + 2) * 128
+    assert sum(s["corrupt"] for s in verify) == 1

@@ -156,3 +156,28 @@ def test_a_device_save_of_more_than_half_the_hbm_fits(one_chip):
     per_window = (encode.argument_size_in_bytes
                   + encode.output_size_in_bytes + encode.temp_size_in_bytes)
     assert total + 2 * (flatten + per_window) < V5E_HBM
+
+
+@pytest.mark.parametrize("blocks", [(180,), (3, 210)],
+                         ids=["verify", "save_window"])
+def test_the_crc_compiles(one_chip, blocks):
+    """CRC32C per 512 bytes of 1 MiB blocks: a stripe's data blocks on a
+    verified read, and a 180-of-210 save window's codewords. The chunk
+    transpose, the kernel over one column per chunk and the CRC words are
+    separate programs, as `ops.checksum_many` dispatches them; a stripe's
+    blocks are laid out in one program each."""
+    n = blocks[-1]
+    lanes = n * BLOCK // 512
+    cols = ops._chunk_columns.lower(
+        _spec((n, BLOCK), jnp.uint8, one_chip), nbytes=512).compile()
+    assert cols.memory_analysis().output_size_in_bytes == n * BLOCK
+    lanes *= blocks[0] if len(blocks) > 1 else 1
+    kernel = _compile(
+        lambda a, x: ops._bitmatmul_many(a, x, block_b=None,
+                                         interpret=False),
+        _spec((32, 8 * 512), jnp.uint8, one_chip),
+        _spec((1, 512, lanes), jnp.uint8, one_chip))
+    assert "tpu_custom_call" in kernel.as_text()
+    ops._crc_words.lower(_spec((1, 4, lanes), jnp.uint8, one_chip),
+                         _spec((), jnp.uint32, one_chip),
+                         shape=(*blocks, BLOCK // 512)).compile()
